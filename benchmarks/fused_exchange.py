@@ -38,7 +38,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import telemetry
@@ -113,12 +112,12 @@ def build_round_fn(mesh, rel, cfg):
         out, _ = fl.tdm_fla_round(t, rel, "node", N, cfg)
         return jax.tree.map(lambda x: x[None], out)
 
-    # check_rep=False: the fused int8 path may lower through pallas_call,
+    # check_vma=False: the fused int8 path may lower through pallas_call,
     # which has no shard_map replication rule
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=(P("node"),), out_specs=P("node"),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -43,7 +43,6 @@ import time
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import telemetry
@@ -228,9 +227,9 @@ def measured_rows(payload_leaves, leaf_elems, antennas, steps, altitude,
                 jax.tree.map(lambda x: x[None], z) for z in (out, nc, npend)
             )
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(P("node"),) * 3,
-            out_specs=(P("node"),) * 3, check_rep=False,
+            out_specs=(P("node"),) * 3, check_vma=False,
         ))
         stats, wall = measure(fn, (tree, carry, pend), reps)
         want = aggregation.expected_window_collectives(

@@ -39,7 +39,6 @@ import time
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro import telemetry
@@ -140,9 +139,9 @@ def measured_rows(payload_bytes, payload_leaves, leaf_elems, antennas, steps,
         tree = make_tree(payload_leaves, leaf_elems, n=n)
 
         def wrap(body):
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=(P("node"),), out_specs=P("node"),
-                check_rep=False,
+                check_vma=False,
             ))
 
         def groundseg_body(compression):

@@ -23,6 +23,10 @@ the framework's own perf tables.
               (subprocess: 8 devs)
   roofline    the 40-cell dry-run roofline table (reads experiments/dryrun)
 
+Every benchmark runs as its own ``python -m benchmarks.<module>`` process
+and this parent never imports JAX: an accelerator belongs to one process at
+a time, so a parent holding it would lock its children out.
+
 ``python -m benchmarks.run``            runs everything quick
 ``python -m benchmarks.run --only fig3 --full``
 
@@ -36,8 +40,6 @@ files, or the whole directory, as ``--run``).
 from __future__ import annotations
 
 import argparse
-import contextlib
-import io
 import json
 import os
 import pathlib
@@ -80,57 +82,11 @@ def _write_summary(out_dir, name, rows, counters):
           f"{len(counters)} telemetry counters)", flush=True)
 
 
-class _Tee(io.TextIOBase):
-    """Pass stdout through while keeping a copy for BENCH-line parsing."""
-
-    def __init__(self, stream):
-        self.stream = stream
-        self.captured: list = []
-        self._buf = ""
-
-    def write(self, s):
-        self.stream.write(s)
-        self._buf += s
-        while "\n" in self._buf:
-            line, self._buf = self._buf.split("\n", 1)
-            self.captured.append(line)
-        return len(s)
-
-    def flush(self):
-        self.stream.flush()
-
-    def finish(self):
-        if self._buf:
-            self.captured.append(self._buf)
-            self._buf = ""
-        return self.captured
-
-
-def _inproc_bench(name: str, fn, out_dir):
-    """Run an in-process benchmark under its own flight-recorder scope,
-    tee its stdout, and write the BENCH_<name>.json summary."""
-    tee = _Tee(sys.stdout)
-    counters = {}
-    try:
-        from repro import telemetry
-    except ImportError:
-        telemetry = None
-    with contextlib.redirect_stdout(tee):
-        if telemetry is None:
-            fn()
-        else:
-            with telemetry.record_scope():
-                fn()
-                counters = telemetry.counters_snapshot()
-    rows, printed = _parse_lines(tee.finish())
-    counters.update(printed)
-    _write_summary(out_dir, name, rows, counters)
-
-
 def _subprocess_bench(module: str, extra_args=(), timeout: int = 1200,
                       name: str = None, out_dir=None):
-    """Run a benchmark module in its own process (needed when it forces its
-    own XLA device count, which locks at first jax init)."""
+    """Run a benchmark module in its own process (the only way a benchmark
+    runs: each owns its device, and some force their own XLA device count,
+    which locks at first jax init)."""
     root = pathlib.Path(__file__).resolve().parents[1]
     proc = subprocess.run(
         [sys.executable, "-m", module, *extra_args],
@@ -158,45 +114,32 @@ def main(argv=None):
     args = p.parse_args(argv)
     want = lambda n: args.only is None or args.only == n
     out_dir = args.out_dir
+    full = ["--full"] if args.full else []
+    full_or_smoke = full or ["--smoke"]
 
     if want("fig3"):
         _banner("fig3: paper Fig.3 — TDM primitive scaling over a clique")
-        from benchmarks import fig3_tdm_scaling
-        _inproc_bench(
-            "fig3",
-            lambda: fig3_tdm_scaling.main(["--full"] if args.full else []),
-            out_dir,
-        )
+        _subprocess_bench("benchmarks.fig3_tdm_scaling", full, name="fig3",
+                          out_dir=out_dir)
 
     if want("constellation"):
         _banner("constellation: geometry-driven round time / ISL traffic sweep")
-        from benchmarks import constellation_round_time
-        _inproc_bench(
-            "constellation",
-            lambda: constellation_round_time.main(
-                ["--full"] if args.full else []
-            ),
-            out_dir,
-        )
+        _subprocess_bench("benchmarks.constellation_round_time", full,
+                          name="constellation", out_dir=out_dir)
 
     if want("optimizer"):
         _banner("optimizer: greedy vs rate-aware TDM schedules")
-        from benchmarks import schedule_optimizer
-        _inproc_bench(
-            "optimizer",
-            lambda: schedule_optimizer.main(["--full"] if args.full else []),
-            out_dir,
-        )
+        _subprocess_bench("benchmarks.schedule_optimizer", full,
+                          name="optimizer", out_dir=out_dir)
 
     if want("gossip"):
         _banner("gossip: consensus speed per TDM topology (paper P2)")
-        from benchmarks import gossip_convergence
-        _inproc_bench("gossip", lambda: gossip_convergence.main([]), out_dir)
+        _subprocess_bench("benchmarks.gossip_convergence", name="gossip",
+                          out_dir=out_dir)
 
     if want("moe"):
         _banner("moe: dispatch useful-FLOPs vs capacity factor")
-        from benchmarks import moe_dispatch
-        _inproc_bench("moe", lambda: moe_dispatch.main([]), out_dir)
+        _subprocess_bench("benchmarks.moe_dispatch", name="moe", out_dir=out_dir)
 
     if want("tdm"):
         _banner("tdm: collective bytes of get1meas / getMeas / int8 (8 devices)")
@@ -205,61 +148,35 @@ def main(argv=None):
 
     if want("fused"):
         _banner("fused: flat-buffer exchange engine vs per-leaf (8 devices)")
-        _subprocess_bench(
-            "benchmarks.fused_exchange",
-            ["--full"] if args.full else ["--smoke"],
-            timeout=3600,
-            name="fused",
-            out_dir=out_dir,
-        )
+        _subprocess_bench("benchmarks.fused_exchange", full_or_smoke,
+                          timeout=3600, name="fused", out_dir=out_dir)
 
     if want("groundseg"):
         _banner("groundseg: sink-based FL vs gossip over the same schedule")
-        _subprocess_bench(
-            "benchmarks.groundseg_round_time",
-            ["--full"] if args.full else ["--smoke"],
-            timeout=3600,
-            name="groundseg",
-            out_dir=out_dir,
-        )
+        _subprocess_bench("benchmarks.groundseg_round_time", full_or_smoke,
+                          timeout=3600, name="groundseg", out_dir=out_dir)
 
     if want("pipeline"):
         _banner("pipeline: pipelined multi-window groundseg round throughput")
-        _subprocess_bench(
-            "benchmarks.groundseg_pipeline",
-            ["--full"] if args.full else ["--smoke"],
-            timeout=3600,
-            name="pipeline",
-            out_dir=out_dir,
-        )
+        _subprocess_bench("benchmarks.groundseg_pipeline", full_or_smoke,
+                          timeout=3600, name="pipeline", out_dir=out_dir)
 
     if want("serving"):
         _banner("serving: TDM-slotted inference over the ground segment")
-        _subprocess_bench(
-            "benchmarks.serving_throughput",
-            ["--full"] if args.full else ["--smoke"],
-            timeout=3600,
-            name="serving",
-            out_dir=out_dir,
-        )
+        _subprocess_bench("benchmarks.serving_throughput", full_or_smoke,
+                          timeout=3600, name="serving", out_dir=out_dir)
 
     if want("plan_synthesis"):
         _banner("plan_synthesis: mega-constellation plan pipeline vs legacy")
-        from benchmarks import plan_synthesis
-        _inproc_bench(
-            "plan_synthesis",
-            lambda: plan_synthesis.main(["--full"] if args.full else ["--smoke"]),
-            out_dir,
-        )
+        _subprocess_bench("benchmarks.plan_synthesis", full_or_smoke,
+                          name="plan_synthesis", out_dir=out_dir)
 
     if want("roofline"):
         _banner("roofline: 40-cell dry-run table (single-pod 16x16)")
-        from benchmarks import roofline
         d = pathlib.Path("experiments/dryrun")
         if (d / "single").exists():
-            _inproc_bench(
-                "roofline", lambda: roofline.main(["--mesh", "single"]), out_dir
-            )
+            _subprocess_bench("benchmarks.roofline", ["--mesh", "single"],
+                              name="roofline", out_dir=out_dir)
         else:
             print("experiments/dryrun/single missing — run "
                   "`python -m repro.launch.dryrun --mesh single` first")

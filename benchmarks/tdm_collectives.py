@@ -26,7 +26,6 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import tdm
@@ -39,7 +38,9 @@ SIZE = 1 << 16   # payload floats per node
 
 def compile_and_stats(fn, x):
     mesh = jax.make_mesh((N,), ("node",))
-    f = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("node"), out_specs=P("node")))
+    f = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=P("node"), out_specs=P("node"), check_vma=False
+    ))
     lowered = f.lower(x)
     compiled = lowered.compile()
     stats = collective_stats(compiled.as_text())

@@ -46,6 +46,7 @@ from repro.data import pipeline
 from repro.launch import fl_train
 from repro.models.config import ShapeConfig
 from repro.optim import adamw
+from repro.launch import mesh as mesh_lib
 
 
 ROUNDS = 10
@@ -108,8 +109,10 @@ def main_tdm(rounds=ROUNDS):
             f"{w.t_end_s/60.0:5.1f}] min  {w.mean_rate_bps/1e6:.0f} Mb/s"
         )
 
-    mesh = jax.make_mesh((n_sats,), ("data",))
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, n_sats)
+    mesh = mesh_lib.make_mesh((n_sats,), ("data",))
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, n_sats, mesh
+    )
     alive = set(range(n_sats))
 
     def on_round(log):
@@ -158,8 +161,10 @@ def main_groundseg(rounds=ROUNDS, pipeline_depth=1, max_staleness=0):
             f"{rc.bytes_on_isl/1e9:.2f} GB on ISL"
         )
 
-    mesh = jax.make_mesh((n_nodes,), ("data",))
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, n_nodes)
+    mesh = mesh_lib.make_mesh((n_nodes,), ("data",))
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, n_nodes, mesh
+    )
     alive = set(range(n_nodes))
     # lose a satellite one round before the end so at least one later round
     # actually exercises the rerouting path (rounds=2 -> fail after round 0)

@@ -1,5 +1,4 @@
-"""Checkpointing: msgpack + zstd (stdlib zlib fallback when the optional
-``zstandard`` package is absent), async save, content hashes, elastic
+"""Checkpointing: msgpack + zstd, async save, content hashes, elastic
 reshard-on-restore.
 
 Layout per checkpoint directory (``<dir>/step_<N>/``):
@@ -33,14 +32,7 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
-import zlib
-
-try:  # optional: better ratio/speed when available
-    import zstandard
-except ImportError:  # pragma: no cover - depends on environment
-    zstandard = None
-
-_ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
+import zstandard
 
 _SAVE_LOCK = threading.Lock()
 _PENDING: List[threading.Thread] = []
@@ -66,20 +58,11 @@ def _path_str(p) -> str:
 
 
 def _compress(raw: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=3).compress(raw)
-    return zlib.compress(raw, level=6)
+    return zstandard.ZstdCompressor(level=3).compress(raw)
 
 
 def _decompress(raw: bytes) -> bytes:
-    if raw[:4] == _ZSTD_MAGIC:
-        if zstandard is None:
-            raise ImportError(
-                "checkpoint was written with zstd but the 'zstandard' "
-                "package is not installed (pip install zstandard)"
-            )
-        return zstandard.ZstdDecompressor().decompress(raw)
-    return zlib.decompress(raw)
+    return zstandard.ZstdDecompressor().decompress(raw)
 
 
 def _tree_def_hash(keys: List[str]) -> str:

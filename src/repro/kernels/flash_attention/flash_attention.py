@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this class as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -139,7 +136,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(q, k, v)

@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this class as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(
     x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_out_ref, state_scr,
@@ -113,7 +110,7 @@ def ssd_scan_fwd(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )(x, dt, A, B, C)

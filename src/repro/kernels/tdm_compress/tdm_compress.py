@@ -12,11 +12,22 @@ time: ``acc += w * (q * scale)``. Doing dequant and accumulate in one kernel
 keeps the int8 payload from ever materializing as fp32 in HBM — a single
 pass over the buffer per matching.
 
-Grid (n/block,); tiles (block,) live fully in VMEM (block = 1024 fp32 =
-4 KiB in, 1 KiB out). Scales are written per block (fp32). Arbitrary
-lengths are handled by zero-padding up to the next block boundary (zeros
-never raise a block's absmax, and padded lanes are sliced off on the way
-out).
+Layout: a flat buffer of ``nb`` blocks is viewed as ``(nb, sub, lanes)``
+— one ``(sub, lanes)`` slab per quantization block, with ``lanes = 128``
+whenever ``block`` is a multiple of 128 (else ``lanes = block``). At the
+default ``block = 1024`` a slab is one (8, 128) vreg tile, and the view is
+a bitcast of the flat array's (1024)-tiled HBM layout: no relayout copy on
+the way in or out, for f32 and int8 alike. Each grid step owns ``rows``
+whole blocks. Per-block values (scales, top-k values and indices) are
+lane-dense: ``(1, nb)`` and ``(k, nb)`` with blocks along lanes, each
+tile ``(1, rows)`` / ``(k, rows)`` — a ``(nb, 1)`` layout would pad every
+scalar to a 128-lane row. Every reduction (absmax, top-k selection) runs
+within one slab, so a block's semantics never depend on the tiling.
+The last tile may overhang ``nb``: the rows it reads past the end are
+never written back, so no buffer is padded or copied to a multiple of the
+tile. Lengths that are not block multiples are zero-padded up to the next
+block boundary (zeros never raise a block's absmax, and padded lanes are
+sliced off on the way out).
 """
 
 from __future__ import annotations
@@ -28,8 +39,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 ships this class as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+# Target elements per tile (512 KiB of f32): large enough that per-step
+# overhead stays small, far below the scoped VMEM limit even with every
+# operand double-buffered.
+_TILE_ELEMS = 128 * 1024
+_ROW_ALIGN = 128  # blocks run along lanes in the per-block (k, rows) tiles
 
 
 def _pad_to_block(x: jax.Array, block: int) -> jax.Array:
@@ -40,81 +54,164 @@ def _pad_to_block(x: jax.Array, block: int) -> jax.Array:
     return x
 
 
+def _slab(block: int):
+    """``(sub, lanes)`` shape of one block's slab."""
+    lanes = 128 if block % 128 == 0 else block
+    return block // lanes, lanes
+
+
+def _rows(nb: int, block: int) -> int:
+    """Blocks per tile: a multiple of 128 near ``_TILE_ELEMS``, or ``nb``."""
+    rows = max(_ROW_ALIGN, _TILE_ELEMS // block // _ROW_ALIGN * _ROW_ALIGN)
+    return nb if nb <= rows else rows
+
+
+def _slabs(x: jax.Array, block: int) -> jax.Array:
+    return x.reshape((x.shape[0] // block,) + _slab(block))
+
+
+def _tiled_call(
+    kernel, nb: int, block: int, in_widths, out_shapes, interpret, aliases=None
+):
+    """``pallas_call`` over per-block operands tiled ``rows`` blocks at a time.
+
+    ``in_widths``/``out_shapes`` describe each operand: ``"slab"`` for the
+    ``(nb, sub, lanes)`` payload view, an int ``w`` for a lane-dense
+    ``(w, nb)`` per-block array (1 for scales, ``k`` for top-k payloads),
+    and ``None`` for the ``(1, 1)`` scalar weight, which every step reads
+    whole; out shapes are ``(width, dtype)``. ``aliases`` maps an input to
+    the output written in its place (the accumulators update in place: no
+    second buffer-sized allocation per matching)."""
+    rows = _rows(nb, block)
+    sub, lanes = _slab(block)
+
+    def spec(width):
+        if width is None:
+            return pl.BlockSpec((1, 1), lambda i: (0, 0))
+        if width == "slab":
+            return pl.BlockSpec((rows, sub, lanes), lambda i: (i, 0, 0))
+        return pl.BlockSpec((width, rows), lambda i: (0, i))
+
+    def shape(width, dtype):
+        dims = (nb, sub, lanes) if width == "slab" else (width, nb)
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(nb, rows),),
+        in_specs=[spec(w) for w in in_widths],
+        out_specs=[spec(w) for w, _ in out_shapes],
+        out_shape=[shape(w, d) for w, d in out_shapes],
+        input_output_aliases=aliases or {},
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+    )
+
+
+def _block_reduce(op, x):
+    """Reduce each ``(sub, lanes)`` slab of ``(rows, sub, lanes)`` to
+    ``(rows, 1, 1)`` (lanes first, then sublanes)."""
+    return op(op(x, axis=2, keepdims=True), axis=1, keepdims=True)
+
+
+def _to_lanes(v):
+    """Per-block ``(rows, 1, 1)`` -> lane-dense ``(1, rows)`` (through a
+    128-lane transpose, which Mosaic lowers natively)."""
+    col = v[:, 0, :]
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[0:1, :]
+
+
+def _to_slabs(row):
+    """Lane-dense ``(1, rows)`` -> ``(rows, 1, 1)``, broadcastable against
+    the ``(rows, sub, lanes)`` slabs."""
+    col = jnp.broadcast_to(row, (128, row.shape[1])).T[:, 0:1]
+    return col[:, :, None]
+
+
 def _quant_scaled_kernel(x_ref, s_ref, q_ref):
-    x = x_ref[...].astype(jnp.float32)                    # (1, block)
-    q_ref[...] = jnp.clip(
-        jnp.round(x / s_ref[0, 0]), -127, 127
-    ).astype(jnp.int8)
+    x = x_ref[...].astype(jnp.float32)                    # (rows, sub, lanes)
+    s = _to_slabs(s_ref[...])                             # (rows, 1, 1)
+    q_ref[...] = jnp.clip(jnp.round(x / s), -127, 127).astype(jnp.int8)
 
 
 def _topk_kernel(k: int, x_ref, dense_ref, v_ref, i_ref):
-    """Blockwise top-|x| selection: k rounds of masked argmax over the tile.
+    """Blockwise top-|x| selection: k rounds of masked argmax per slab.
 
     Selection key is |x| with NaN ranked above +inf; ties break toward the
     lowest index — the exact order of the stable descending argsort in
     ``topk_sparsify_ref``, so vals/idxs match the oracle elementwise.
     """
-    x = x_ref[...].astype(jnp.float32)                    # (1, block)
-    block = x.shape[1]
+    x = x_ref[...].astype(jnp.float32)                    # (rows, sub, lanes)
+    rows, sub, lanes = x.shape
     key = jnp.where(jnp.isnan(x), jnp.inf, jnp.abs(x))
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)  # (1, block)
-    out_pos = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    # block-local index of every element
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) * lanes
+    col = col + jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    out_pos = jax.lax.broadcasted_iota(jnp.int32, (k, rows), 0)
 
     def body(t, carry):
-        live, sel, vals, idxs = carry
-        hit = live == jnp.max(live)
-        # lowest index among the maxima (killed lanes hold key -1, below
-        # every remaining |x| >= 0, so they can never be re-picked)
-        idx_t = jnp.min(jnp.where(hit, col, block))
+        live, vals, idxs = carry
+        hit = live == _block_reduce(jnp.max, live)
+        # lowest index among the slab's maxima (killed lanes hold key -1,
+        # below every remaining |x| >= 0, so they can never be re-picked)
+        idx_t = _block_reduce(jnp.min, jnp.where(hit, col, sub * lanes))
         chosen = col == idx_t
-        v_t = jnp.sum(jnp.where(chosen, x, 0.0))
+        v_t = _block_reduce(jnp.sum, jnp.where(chosen, x, 0.0))
         at_t = out_pos == t
         return (
             jnp.where(chosen, -1.0, live),
-            sel | chosen,
-            jnp.where(at_t, v_t, vals),
-            jnp.where(at_t, idx_t, idxs),
+            jnp.where(at_t, _to_lanes(v_t), vals),
+            jnp.where(at_t, _to_lanes(idx_t), idxs),
         )
 
     init = (
         key,
-        jnp.zeros(x.shape, dtype=jnp.bool_),
-        jnp.zeros((1, k), jnp.float32),
-        jnp.zeros((1, k), jnp.int32),
+        jnp.zeros((k, rows), jnp.float32),
+        jnp.zeros((k, rows), jnp.int32),
     )
-    _, sel, vals, idxs = jax.lax.fori_loop(0, k, body, init)
-    dense_ref[...] = jnp.where(sel, x, 0.0)
+    # the selected lanes are exactly the killed ones (a mask is not carried
+    # through the loop: Mosaic cannot hold boolean vectors in loop state)
+    live, vals, idxs = jax.lax.fori_loop(0, k, body, init)
+    dense_ref[...] = jnp.where(live < 0, x, 0.0)
     v_ref[...] = vals
     i_ref[...] = idxs
 
 
 def _scatter_acc_kernel(v_ref, i_ref, acc_ref, w_ref, out_ref):
-    vals = v_ref[...].astype(jnp.float32)                 # (1, k)
-    idxs = i_ref[...]                                     # (1, k)
-    col = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)  # (1, block)
-    hit = idxs[0, :, None] == col[0, None, :]             # (k, block)
-    dense = jnp.sum(
-        jnp.where(hit, vals[0, :, None], 0.0), axis=0, keepdims=True
+    vals = v_ref[...].astype(jnp.float32)                 # (k, rows)
+    idxs = i_ref[...]                                     # (k, rows)
+    shape = acc_ref.shape                                 # (rows, sub, lanes)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1) * shape[2]
+    col = col + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, idxs.shape, 0)
+
+    def body(t, dense):
+        # entry t of every block; the masked sums pick exactly one element
+        at_t = kpos == t
+        v_t = jnp.sum(jnp.where(at_t, vals, 0.0), axis=0, keepdims=True)
+        i_t = jnp.sum(jnp.where(at_t, idxs, 0), axis=0, keepdims=True)
+        return dense + jnp.where(col == _to_slabs(i_t), _to_slabs(v_t), 0.0)
+
+    dense = jax.lax.fori_loop(
+        0, idxs.shape[0], body, jnp.zeros(shape, jnp.float32)
     )
     out_ref[...] = acc_ref[...] + w_ref[0, 0] * dense
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)                    # (1, block)
-    absmax = jnp.max(jnp.abs(x))
-    scale = jnp.maximum(absmax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
-    q_ref[...] = q
-    s_ref[0, 0] = scale
+    x = x_ref[...].astype(jnp.float32)                    # (rows, sub, lanes)
+    scale = jnp.maximum(_block_reduce(jnp.max, jnp.abs(x)), 1e-12) / 127.0
+    q_ref[...] = jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8)
+    s_ref[...] = _to_lanes(scale)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * _to_slabs(s_ref[...])
 
 
 def _dequant_acc_kernel(q_ref, s_ref, acc_ref, w_ref, out_ref):
     out_ref[...] = acc_ref[...] + w_ref[0, 0] * (
-        q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+        q_ref[...].astype(jnp.float32) * _to_slabs(s_ref[...])
     )
 
 
@@ -128,22 +225,11 @@ def quantize_fwd(x: jax.Array, *, block: int = 1024, interpret: bool = False):
     n = x.shape[0]
     x = _pad_to_block(x, block)
     nb = x.shape[0] // block
-    x2 = x.reshape(nb, block)
-    q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), jnp.int8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(x2)
+    q, s = _tiled_call(
+        _quant_kernel, nb, block, ["slab"],
+        [("slab", jnp.int8), (1, jnp.float32)],
+        interpret,
+    )(_slabs(x, block))
     return q.reshape(nb * block)[:n], s.reshape(nb)
 
 
@@ -154,18 +240,10 @@ def dequantize_fwd(q: jax.Array, scales: jax.Array, *, block: int = 1024,
     q = _pad_to_block(q, block)
     nb = q.shape[0] // block
     assert scales.shape[0] == nb, (scales.shape, nb, block)
-    x = pl.pallas_call(
-        _dequant_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(q.reshape(nb, block), scales.reshape(nb, 1))
+    (x,) = _tiled_call(
+        _dequant_kernel, nb, block, ["slab", 1], [("slab", jnp.float32)],
+        interpret,
+    )(_slabs(q, block), scales.reshape(1, nb))
     return x.reshape(nb * block)[:n]
 
 
@@ -191,20 +269,12 @@ def dequant_accumulate_fwd(
     nb = q.shape[0] // block
     assert scales.shape[0] == nb, (scales.shape, nb, block)
     w2 = jnp.asarray(w, jnp.float32).reshape(1, 1)
-    out = pl.pallas_call(
-        _dequant_acc_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(q.reshape(nb, block), scales.reshape(nb, 1), acc.reshape(nb, block), w2)
+    (out,) = _tiled_call(
+        _dequant_acc_kernel, nb, block, ["slab", 1, "slab", None],
+        [("slab", jnp.float32)],
+        interpret,
+        aliases={2: 0},
+    )(_slabs(q, block), scales.reshape(1, nb), _slabs(acc, block), w2)
     return out.reshape(nb * block)[:n]
 
 
@@ -227,18 +297,10 @@ def quantize_scaled_fwd(
     x = _pad_to_block(x.astype(jnp.float32), block)
     nb = x.shape[0] // block
     assert scales.shape[0] == nb, (scales.shape, nb, block)
-    q = pl.pallas_call(
-        _quant_scaled_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.int8),
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(x.reshape(nb, block), scales.reshape(nb, 1))
+    (q,) = _tiled_call(
+        _quant_scaled_kernel, nb, block, ["slab", 1], [("slab", jnp.int8)],
+        interpret,
+    )(_slabs(x, block), scales.reshape(1, nb))
     return q.reshape(nb * block)[:n]
 
 
@@ -269,24 +331,12 @@ def topk_sparsify_fwd(
             jnp.zeros((nb, 0), jnp.float32),
             jnp.zeros((nb, 0), jnp.int32),
         )
-    dense, vals, idxs = pl.pallas_call(
-        functools.partial(_topk_kernel, k),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, block), jnp.float32),
-            jax.ShapeDtypeStruct((nb, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, k), jnp.int32),
-        ],
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(x.reshape(nb, block))
-    return dense.reshape(nb * block)[:n], vals, idxs
+    dense, vals, idxs = _tiled_call(
+        functools.partial(_topk_kernel, k), nb, block, ["slab"],
+        [("slab", jnp.float32), (k, jnp.float32), (k, jnp.int32)],
+        interpret,
+    )(_slabs(x, block))
+    return dense.reshape(nb * block)[:n], vals.T, idxs.T
 
 
 def scatter_accumulate_fwd(
@@ -315,18 +365,10 @@ def scatter_accumulate_fwd(
     if k == 0:
         return acc.reshape(nb * block)[:n]
     w2 = jnp.asarray(w, jnp.float32).reshape(1, 1)
-    out = pl.pallas_call(
-        _scatter_acc_kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
-        interpret=interpret,
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
-    )(vals, idxs, acc.reshape(nb, block), w2)
+    (out,) = _tiled_call(
+        _scatter_acc_kernel, nb, block, [k, k, "slab", None],
+        [("slab", jnp.float32)],
+        interpret,
+        aliases={2: 0},
+    )(vals.T, idxs.T, _slabs(acc, block), w2)
     return out.reshape(nb * block)[:n]

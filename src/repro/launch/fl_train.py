@@ -31,8 +31,7 @@ from typing import Any, Callable, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import telemetry
 from repro.core import fl, tdm
@@ -52,22 +51,30 @@ class FLConfig:
     fused: bool = True              # flat-buffer exchange engine (core/fused)
 
 
-def _stack_init(key, cfg: ModelConfig, opt_cfg, n_nodes: int):
-    """Per-node states, stacked on a leading node axis.
+def _stack_init(
+    key, cfg: ModelConfig, opt_cfg, n_nodes: int, mesh: Mesh, axis="data"
+):
+    """Per-node states, stacked on a leading node axis sharded over
+    ``mesh``'s ``axis`` (a name, or a tuple of names for a 2D node mesh).
 
     Every node starts from the SAME init (consensus start: seed is
     ``fold_in(key, 0)`` for all of them), so the model/opt state is built
-    once and broadcast — not re-initialized n_nodes times.
+    once and broadcast — not re-initialized n_nodes times. The stack is
+    created already sharded: each device only ever holds its own node.
     """
-    params, _ = registry.bundle(cfg).init(jax.random.fold_in(key, 0))
-    state = {
-        "params": params,
-        "opt": adamw.init_opt_state(params, opt_cfg),
-        "step": jnp.zeros((), jnp.int32),
-    }
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_nodes,) + x.shape), state
-    )
+
+    def init(key):
+        params, _ = registry.bundle(cfg).init(jax.random.fold_in(key, 0))
+        state = {
+            "params": params,
+            "opt": adamw.init_opt_state(params, opt_cfg),
+            "step": jnp.zeros((), jnp.int32),
+        }
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n_nodes,) + x.shape), state
+        )
+
+    return jax.jit(init, out_shardings=NamedSharding(mesh, P(axis)))(key)
 
 
 def build_fl_round(
@@ -125,12 +132,12 @@ def build_fl_round(
         return state, local_loss[None]
 
     spec_state = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         node_round,
         mesh=mesh,
         in_specs=(spec_state, spec_state),
         out_specs=(spec_state, P(axis)),
-        check_rep=False,  # model-internal scans carry node-invariant zeros;
+        check_vma=False,  # model-internal scans carry node-invariant zeros;
                           # vma tracking would demand pcasts throughout
     )
     return jax.jit(fn, donate_argnums=(0,))
@@ -206,12 +213,12 @@ def build_hierarchical_fl_round(
         return state, local_loss[None]
 
     spec_state = P((pod_axis, data_axis))
-    fn = shard_map(
+    fn = jax.shard_map(
         node_round,
         mesh=mesh,
         in_specs=(spec_state, spec_state),
         out_specs=(spec_state, P((pod_axis, data_axis))),
-        check_rep=False,  # same reason as build_fl_round (+ pallas int8 path)
+        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
     )
     return jax.jit(fn, donate_argnums=(0,))
 
@@ -599,12 +606,12 @@ def build_groundseg_round(
         return state, local_loss[None]
 
     spec_state = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         node_round,
         mesh=mesh,
         in_specs=(spec_state, spec_state),
         out_specs=(spec_state, P(axis)),
-        check_rep=False,  # same reason as build_fl_round (+ pallas int8 path)
+        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
     )
     return jax.jit(fn, donate_argnums=(0,))
 
@@ -679,12 +686,12 @@ def build_pipelined_groundseg_round(
         return state, aux, local_loss[None]
 
     spec_state = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         node_round,
         mesh=mesh,
         in_specs=(spec_state, spec_state, spec_state),
         out_specs=(spec_state, spec_state, P(axis)),
-        check_rep=False,  # same reason as build_fl_round (+ pallas int8 path)
+        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
     )
     return jax.jit(fn, donate_argnums=(0, 1))
 

@@ -27,7 +27,18 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             f"run under XLA_FLAGS=--xla_force_host_platform_device_count={need}"
         )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _auto_mesh(shape, axes, devices)
+
+
+def _auto_mesh(shape, axes, devices):
+    # every axis Auto: shardings here are hints the compiler propagates
+    # (with_sharding_constraint, shard_map specs), not explicit-mode types
+    return jax.make_mesh(
+        shape,
+        axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
@@ -35,7 +46,7 @@ def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     devices = jax.devices()[:need]
     if len(devices) < need:
         raise RuntimeError(f"mesh {shape} needs {need} devices")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _auto_mesh(shape, axes, devices)
 
 
 # TPU v5e hardware constants (roofline denominators)

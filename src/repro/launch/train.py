@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import archs
 from repro.data import pipeline
+from repro.launch import mesh as mesh_lib
 from repro.launch import sharding as shlib
 from repro.launch import steps as steps_lib
 from repro.models.config import ShapeConfig
@@ -56,8 +57,7 @@ def main(argv=None):
     rules = None
     if n_dev > 1:
         axes = {"data": min(n_dev, max(1, args.batch)), "model": 1}
-        mesh = jax.make_mesh((axes["data"], 1), ("data", "model"),
-                             devices=jax.devices()[: axes["data"]])
+        mesh = mesh_lib.make_mesh((axes["data"], 1), ("data", "model"))
         rules = shlib.rules_for(mesh, cfg.fsdp)
 
     train_step = jax.jit(

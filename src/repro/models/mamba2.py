@@ -205,8 +205,13 @@ def ssd_chunked(
             "bqhn,bshn->bhqs", Ch, Bh, preferred_element_type=jnp.float32
         )
         cum_t = cum.transpose(0, 2, 1)    # (B,H,Q)
-        Ldec = jnp.exp(cum_t[:, :, :, None] - cum_t[:, :, None, :])
         tri = jnp.tril(jnp.ones((chunk, chunk), dtype=bool))
+        # mask the exponent, not the product: for s > t, cum_t - cum_s is a
+        # growing positive sum that overflows exp() in long chunks, and the
+        # masked inf would turn the backward pass into inf * 0 = NaN
+        Ldec = jnp.exp(jnp.where(
+            tri[None, None], cum_t[:, :, :, None] - cum_t[:, :, None, :], -jnp.inf
+        ))
         W = jnp.where(tri[None, None], CB * Ldec, 0.0)
         W = W * dtq.transpose(0, 2, 1)[:, :, None, :]          # weight dt_s
         y_intra = jnp.einsum(

@@ -91,8 +91,7 @@ class ModelDecoder:
     ):
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from repro.models import registry
 
@@ -102,7 +101,6 @@ class ModelDecoder:
         self.batch = batch
         self.max_len = max_len
         self.bundle = registry.bundle(cfg)
-        self.params, _ = self.bundle.init(jax.random.PRNGKey(seed))
         if mesh is None:
             devs = jax.devices()
             if len(devs) < n_replicas:
@@ -113,10 +111,19 @@ class ModelDecoder:
             mesh = Mesh(np.array(devs[:n_replicas]), ("replica",))
         self.mesh = mesh
 
-        cache0 = self.bundle.init_cache(batch, max_len)
-        self._cache = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n_replicas,) + x.shape), cache0
-        )
+        # params replicated and caches stacked, both placed at creation:
+        # each device only ever holds its own replica's cache
+        self.params = jax.jit(
+            lambda key: self.bundle.init(key)[0],
+            out_shardings=NamedSharding(mesh, P()),
+        )(jax.random.PRNGKey(seed))
+        self._cache = jax.jit(
+            lambda: jax.tree.map(
+                lambda x: jnp.broadcast_to(x, (n_replicas,) + x.shape),
+                self.bundle.init_cache(batch, max_len),
+            ),
+            out_shardings=NamedSharding(mesh, P("replica")),
+        )()
         self._last = np.zeros((n_replicas, batch), np.int64)
         self._prefill_progs: Dict[int, object] = {}
 
@@ -130,12 +137,12 @@ class ModelDecoder:
             return jax.tree.map(lambda x: x[None], merged), nxt[None]
 
         self._decode = jax.jit(
-            shard_map(
+            jax.shard_map(
                 decode_body,
                 mesh=mesh,
                 in_specs=(P(), P("replica"), P("replica"), P("replica")),
                 out_specs=(P("replica"), P("replica")),
-                check_rep=False,
+                check_vma=False,
             ),
             donate_argnums=(1,),
         )
@@ -145,7 +152,6 @@ class ModelDecoder:
         if prog is not None:
             return prog
         jax, jnp = self._jax, self._jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def body(params, cache, toks, admit):
@@ -160,12 +166,12 @@ class ModelDecoder:
             return jax.tree.map(lambda x: x[None], merged), nxt[None]
 
         prog = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body,
                 mesh=self.mesh,
                 in_specs=(P(), P("replica"), P("replica"), P("replica")),
                 out_specs=(P("replica"), P("replica")),
-                check_rep=False,
+                check_vma=False,
             ),
             donate_argnums=(1,),
         )

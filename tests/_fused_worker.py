@@ -19,13 +19,13 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import fl, fused, tdm
 from repro.core.relation import Relation
 from repro.core.schedule import ring
 from repro.launch.hlo_stats import collective_stats
+from repro.launch import mesh as mesh_lib
 
 N = 8
 mesh = Mesh(np.array(jax.devices()[:N]), ("node",))
@@ -55,12 +55,12 @@ def round_fn(rel, cfg, **kw):
             out, _ = fl.tdm_fla_round(t, rel, "node", N, cfg)
         return jax.tree.map(lambda x: x[None], out)
 
-    # check_rep=False: the Pallas quantization kernels have no replication
+    # check_vma=False: the Pallas quantization kernels have no replication
     # rule (same reason build_fl_round disables it)
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=(P("node"),), out_specs=P("node"),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -251,9 +251,9 @@ def test_choco_fused_converges():
         return jax.tree.map(lambda x: x[None], t)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             rounds, mesh=mesh, in_specs=(P("node"),), out_specs=P("node"),
-            check_rep=False,
+            check_vma=False,
         )
     )
     got = f(tree)
@@ -288,9 +288,9 @@ def hier_fn(compression, quant_impl="auto"):
         return jax.tree.map(lambda x: x[None], out)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh2, in_specs=(P(("pod", "data")),),
-            out_specs=P(("pod", "data")), check_rep=False,
+            out_specs=P(("pod", "data")), check_vma=False,
         )
     )
 
@@ -310,9 +310,9 @@ def test_hierarchical_fused():
         return jax.tree.map(lambda x: x[None], out)
 
     f_leaf = jax.jit(
-        shard_map(
+        jax.shard_map(
             leaf_body, mesh=mesh2, in_specs=(P(("pod", "data")),),
-            out_specs=P(("pod", "data")), check_rep=False,
+            out_specs=P(("pod", "data")), check_vma=False,
         )
     )
     got_none = hier_fn("none")(tree)
@@ -375,7 +375,7 @@ def test_build_fl_round_end_to_end():
     cfg = archs.smoke_cfg(archs.get("mamba2-780m"))
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     shape = ShapeConfig("fl", "train", 32, 2)
-    fl_mesh = jax.make_mesh((N,), ("data",))
+    fl_mesh = mesh_lib.make_mesh((N,), ("data",))
     rel = ring(N)
 
     def batch_fn():
@@ -389,7 +389,9 @@ def test_build_fl_round_end_to_end():
     outs = {}
     for fused_flag in (True, False):
         fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1, fused=fused_flag)
-        state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+        state = fl_train._stack_init(
+            jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh
+        )
         step = fl_train.build_fl_round(cfg, opt_cfg, fl_mesh, N, fl_cfg, rel)
         outs[fused_flag] = step(state, batch)
     s_f, loss_f = outs[True]
@@ -413,7 +415,7 @@ def test_build_hierarchical_fl_round_end_to_end():
     cfg = archs.smoke_cfg(archs.get("mamba2-780m"))
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     shape = ShapeConfig("fl", "train", 32, 2)
-    mesh2 = jax.make_mesh((N_PODS, N_DATA), ("pod", "data"))
+    mesh2 = mesh_lib.make_mesh((N_PODS, N_DATA), ("pod", "data"))
     intra = Relation.clique(list(range(N_DATA)))
     inter = ring(N_PODS)
 
@@ -428,7 +430,9 @@ def test_build_hierarchical_fl_round_end_to_end():
     outs = {}
     for comp in ("none", "int8"):
         fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1, compression=comp)
-        state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+        state = fl_train._stack_init(
+            jax.random.PRNGKey(0), cfg, opt_cfg, N, mesh2, ("pod", "data")
+        )
         step = fl_train.build_hierarchical_fl_round(
             cfg, opt_cfg, mesh2, N_PODS, N_DATA, fl_cfg, intra, inter
         )

@@ -21,7 +21,6 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs import archs
@@ -33,6 +32,7 @@ from repro.launch import fl_train
 from repro.launch.hlo_stats import collective_stats
 from repro.models.config import ShapeConfig
 from repro.optim import adamw
+from repro.launch import mesh as mesh_lib
 
 N_SATS, N_GS = 6, 2
 N = N_SATS + N_GS
@@ -120,9 +120,9 @@ def test_hlo_relay_collective_counts():
                 return jax.tree.map(lambda x: x[None], out)
 
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body, mesh=mesh, in_specs=(P("node"),),
-                    out_specs=P("node"), check_rep=False,
+                    out_specs=P("node"), check_vma=False,
                 )
             )
             stats = collective_stats(fn.lower(tree).compile().as_text())
@@ -157,8 +157,8 @@ def test_fedavg_numerics():
         return jax.tree.map(lambda x: x[None], out)
 
     fn = jax.jit(
-        shard_map(body, mesh=mesh, in_specs=(P("node"),),
-                  out_specs=P("node"), check_rep=False)
+        jax.shard_map(body, mesh=mesh, in_specs=(P("node"),),
+                  out_specs=P("node"), check_vma=False)
     )
     x = np.asarray(tree["w"])
     y = np.asarray(fn(tree)["w"])
@@ -177,8 +177,8 @@ def test_fedavg_numerics():
         return jax.tree.map(lambda x: x[None], out)
 
     f8 = jax.jit(
-        shard_map(body8, mesh=mesh, in_specs=(P("node"),),
-                  out_specs=P("node"), check_rep=False)
+        jax.shard_map(body8, mesh=mesh, in_specs=(P("node"),),
+                  out_specs=P("node"), check_vma=False)
     )
     y8 = np.asarray(f8(tree)["w"])
     err = np.linalg.norm(y8[cov] - y[cov]) / max(np.linalg.norm(y[cov]), 1e-9)
@@ -224,8 +224,8 @@ def test_int8_relay_hop_count_independent():
             return jax.tree.map(lambda x: x[None], out)
 
         fn = jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(P("node"),),
-                      out_specs=P("node"), check_rep=False)
+            jax.shard_map(body, mesh=mesh, in_specs=(P("node"),),
+                      out_specs=P("node"), check_vma=False)
         )
         outs[name] = np.asarray(fn(tree)["w"])
     # hop-count independence: the sinks' pooled global after 3-hop delivery
@@ -266,7 +266,7 @@ def _fl_setup():
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1)
     shape = ShapeConfig("fl", "train", 32, 2)
-    fl_mesh = jax.make_mesh((N,), ("data",))
+    fl_mesh = mesh_lib.make_mesh((N,), ("data",))
 
     def batch_fn(rnd):
         per_node = []
@@ -282,7 +282,9 @@ def test_hierarchical_fl_converges():
     geom, plan = walker_plan()
     cfg, opt_cfg, fl_cfg, fl_mesh, batch_fn = _fl_setup()
     gs_cfg = fl_train.GroundSegConfig(mode="hierarchical", sink_sync_every=2)
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh
+    )
     state, logs = fl_train.run_groundseg_fl(
         cfg, opt_cfg, fl_mesh, N, fl_cfg, gs_cfg, plan, state, batch_fn,
         sinks=SINKS, rounds=4, antennas=2,
@@ -311,7 +313,9 @@ def test_centralized_exact_consensus_on_covered():
     geom, plan = walker_plan()
     cfg, opt_cfg, fl_cfg, fl_mesh, batch_fn = _fl_setup()
     gs_cfg = fl_train.GroundSegConfig(mode="centralized")
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh
+    )
     state, logs = fl_train.run_groundseg_fl(
         cfg, opt_cfg, fl_mesh, N, fl_cfg, gs_cfg, plan, state, batch_fn,
         sinks=SINKS, rounds=2, antennas=2,
@@ -343,7 +347,9 @@ def test_dead_satellite_skip_slot():
     geom, plan = walker_plan()
     cfg, opt_cfg, fl_cfg, fl_mesh, batch_fn = _fl_setup()
     gs_cfg = fl_train.GroundSegConfig(mode="centralized")
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh
+    )
     alive = set(range(N))
     logs_seen = []
 
@@ -368,9 +374,9 @@ def test_dead_satellite_skip_slot():
 #    numerics, and the pipelined driver end to end
 # ---------------------------------------------------------------------------
 def _shard3(body):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("node"),) * 3,
-        out_specs=(P("node"),) * 3, check_rep=False,
+        out_specs=(P("node"),) * 3, check_vma=False,
     ))
 
 
@@ -423,9 +429,9 @@ def test_pipelined_bit_identical_at_trivial_config():
                 )
                 return jax.tree.map(lambda x: x[None], out)
 
-            f_old = jax.jit(shard_map(
+            f_old = jax.jit(jax.shard_map(
                 old_body, mesh=mesh, in_specs=(P("node"),),
-                out_specs=P("node"), check_rep=False,
+                out_specs=P("node"), check_vma=False,
             ))
             carry, pend = _zero_aux(tree)
             y_old = f_old(tree)
@@ -529,7 +535,9 @@ def test_pipelined_fl_end_to_end():
     gs_cfg = fl_train.GroundSegConfig(
         mode="centralized", pipeline_depth=2, max_staleness_windows=2,
     )
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(
+        jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh
+    )
     state, logs = fl_train.run_groundseg_fl(
         cfg, opt_cfg, fl_mesh, N, fl_cfg, gs_cfg, plan, state, batch_fn,
         sinks=SINKS, rounds=3, antennas=2,

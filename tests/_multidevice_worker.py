@@ -20,13 +20,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import fl, tdm
 from repro.core.gossip import metropolis_weights, schedule_mixing_matrix
 from repro.core.ptbfla_sim import run_schedule_getmeas
 from repro.core.relation import Relation
 from repro.core.schedule import TDMSchedule, hypercube_schedule
+from repro.launch import mesh as mesh_lib
 
 N = 8
 mesh = Mesh(np.array(jax.devices()[:N]), ("node",))
@@ -38,7 +38,9 @@ def random_relation(rng: random.Random, n: int = N, p: float = 0.5) -> Relation:
 
 
 def shmap(fn, in_specs, out_specs):
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def check(name, cond):
@@ -356,8 +358,8 @@ def test_constellation_drives_fl_round():
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1)
     shape = ShapeConfig("fl", "train", 32, 2)
-    fl_mesh = jax.make_mesh((N,), ("data",))
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    fl_mesh = mesh_lib.make_mesh((N,), ("data",))
+    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh)
 
     def batch_fn(rnd):
         per_node = []
@@ -406,7 +408,7 @@ def test_optimized_schedule_fl_matches_greedy_bitwise():
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1)
     shape = ShapeConfig("fl", "train", 32, 2)
-    fl_mesh = jax.make_mesh((N,), ("data",))
+    fl_mesh = mesh_lib.make_mesh((N,), ("data",))
 
     def batch_fn(rnd):
         per_node = []
@@ -417,7 +419,7 @@ def test_optimized_schedule_fl_matches_greedy_bitwise():
 
     logs_by_mode = {}
     for optimize in ("greedy", "rate"):
-        state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+        state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N, fl_mesh)
         _, logs = fl_train.run_constellation_fl(
             cfg, opt_cfg, fl_mesh, N, fl_cfg, plan, state, batch_fn,
             rounds=2, optimize=optimize, antennas=N,
@@ -452,7 +454,10 @@ def test_hierarchical_gossip():
             v, intra, inter, data_axis="data", pod_axis="pod", n_data=4, n_pods=2
         )
 
-    f = shard_map(body, mesh=mesh2, in_specs=P(("pod", "data")), out_specs=P(("pod", "data")))
+    f = jax.shard_map(
+        body, mesh=mesh2, in_specs=P(("pod", "data")),
+        out_specs=P(("pod", "data")), check_vma=False,
+    )
     got = np.asarray(jax.jit(f)(x)).reshape(8, 3)
     assert np.allclose(got, x.reshape(8, 3).mean(0), atol=1e-5)
     check("hierarchical pod x data gossip == global mean", True)

@@ -31,6 +31,7 @@ from repro.groundseg import aggregation, routing
 from repro.launch import fl_train
 from repro.models.config import ShapeConfig
 from repro.optim import adamw
+from repro.launch import mesh as mesh_lib
 
 N_SATS, N_GS = 6, 2
 N = N_SATS + N_GS
@@ -83,7 +84,7 @@ def _fl_setup():
     opt_cfg = adamw.OptConfig(peak_lr=5e-3, warmup_steps=2, decay_steps=100)
     fl_cfg = fl_train.FLConfig(mode="tdm", local_steps=1)
     shape = ShapeConfig("fl", "train", 32, 2)
-    mesh = jax.make_mesh((N,), ("data",))
+    mesh = mesh_lib.make_mesh((N,), ("data",))
 
     def batch_fn(rnd):
         per_node = []
@@ -97,7 +98,7 @@ def _fl_setup():
 
 def _run_groundseg(plan, rounds, **kw):
     cfg, opt_cfg, fl_cfg, mesh, batch_fn = _fl_setup()
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N, mesh)
     return fl_train.run_groundseg_fl(
         cfg, opt_cfg, mesh, N, fl_cfg, GS_CFG, plan, state, batch_fn,
         sinks=SINKS, rounds=rounds, antennas=2, payload_bytes=PAYLOAD, **kw
@@ -106,7 +107,7 @@ def _run_groundseg(plan, rounds, **kw):
 
 def _run_tdm(plan, rounds, **kw):
     cfg, opt_cfg, fl_cfg, mesh, batch_fn = _fl_setup()
-    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N)
+    state = fl_train._stack_init(jax.random.PRNGKey(0), cfg, opt_cfg, N, mesh)
     return fl_train.run_constellation_fl(
         cfg, opt_cfg, mesh, N, fl_cfg, plan, state, batch_fn,
         rounds=rounds, **kw

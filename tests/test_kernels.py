@@ -193,6 +193,27 @@ def test_model_ssd_chunked_matches_ref():
     )
 
 
+def test_ssd_chunked_grads_finite_over_long_chunks():
+    """Strong decay over a 256-step chunk: exp(cum_t - cum_s) above the
+    diagonal overflows, and the mask must keep it out of the backward pass
+    (it once turned mamba2-780m's first training step into NaN)."""
+    B, S, H, P, G, N, chunk = 1, 256, 2, 4, 1, 4, 256
+    ks = jax.random.split(jax.random.PRNGKey(17), 4)
+    xh = rand(ks[0], (B, S, H, P), jnp.float32)
+    dt = jnp.full((B, S, H), 0.1, jnp.float32)
+    A = jnp.full((H,), -16.0, jnp.float32)
+    Bv = rand(ks[1], (B, S, G, N), jnp.float32)
+    Cv = rand(ks[2], (B, S, G, N), jnp.float32)
+
+    def loss(xh, dt, Bv, Cv):
+        y, state = mamba_lib.ssd_chunked(xh, dt, A, Bv, Cv, chunk)
+        return jnp.sum(y) + jnp.sum(state)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(xh, dt, Bv, Cv)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+
+
 def test_ssd_decode_step_consistent_with_scan():
     """mamba_decode_step over S steps == chunked scan on the full sequence."""
     B, S, H, P, G, N = 1, 16, 2, 8, 1, 16

@@ -1,0 +1,105 @@
+"""Compile the ``tdm_compress`` kernels for a described TPU v5e chip.
+
+Interpret mode (``tests/test_kernels.py``) checks what the kernels compute;
+it cannot see what the TPU's Mosaic compiler refuses — tile shapes that
+break the (8, 128) / (32, 128) layout rules, or tiles that overflow VMEM.
+These tests lower every kernel at the length of one node's fused
+``mamba2-780m`` buffer for a ``v5e:2x2`` topology that is described, not
+attached, and check that the kernel survived as a ``tpu_custom_call``.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and every test worker imports
+this file.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.tdm_compress import tdm_compress as kern
+
+# one node's fused mamba2-780m parameter buffer (configs/archs.py widths)
+N_ELEMS = 780_148_992
+BLOCK = 1024
+NB = -(-N_ELEMS // BLOCK)
+K = 8  # per-block top-k budget
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _arg(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+CASES = {
+    "quantize": (
+        functools.partial(kern.quantize_fwd, block=BLOCK),
+        [((N_ELEMS,), jnp.float32)],
+    ),
+    "dequantize": (
+        functools.partial(kern.dequantize_fwd, block=BLOCK),
+        [((N_ELEMS,), jnp.int8), ((NB,), jnp.float32)],
+    ),
+    "dequant_accumulate": (
+        functools.partial(kern.dequant_accumulate_fwd, block=BLOCK),
+        [
+            ((N_ELEMS,), jnp.int8),
+            ((NB,), jnp.float32),
+            ((N_ELEMS,), jnp.float32),
+            ((), jnp.float32),
+        ],
+    ),
+    "quantize_scaled": (
+        functools.partial(kern.quantize_scaled_fwd, block=BLOCK),
+        [((N_ELEMS,), jnp.float32), ((NB,), jnp.float32)],
+    ),
+    "topk_sparsify": (
+        functools.partial(kern.topk_sparsify_fwd, k=K, block=BLOCK),
+        [((N_ELEMS,), jnp.float32)],
+    ),
+    "scatter_accumulate": (
+        functools.partial(kern.scatter_accumulate_fwd, block=BLOCK),
+        [
+            ((NB, K), jnp.float32),
+            ((NB, K), jnp.int32),
+            ((N_ELEMS,), jnp.float32),
+            ((), jnp.float32),
+        ],
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
+    fn, shapes = CASES[name]
+    args = [_arg(s, d, one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
