@@ -193,34 +193,40 @@ def clear_spec_cache() -> None:
 
 
 def flatten_pytree(spec: FlatSpec, params: Any) -> Dict[str, jax.Array]:
-    """Pytree -> {dtype name: flat padded buffer} (one concatenate per bucket)."""
+    """Pytree -> {dtype name: flat padded buffer} (one concatenate per
+    bucket), under the ``pack`` name scope."""
     leaves, treedef = jax.tree.flatten(params)
     if treedef != spec.treedef:
         raise ValueError(f"tree mismatch: {treedef} != {spec.treedef}")
     parts: Dict[str, List[jax.Array]] = {b: [] for b in spec.buckets}
     used: Dict[str, int] = {b: 0 for b in spec.buckets}
-    for slot, leaf in zip(spec.slots, leaves):
-        parts[slot.bucket].append(jnp.asarray(leaf).reshape(-1))
-        used[slot.bucket] += slot.size
     out = {}
-    for bucket in spec.buckets:
-        pad = spec.padded_size(bucket) - used[bucket]
-        if pad:
-            parts[bucket].append(jnp.zeros((pad,), dtype=jnp.dtype(bucket)))
-        out[bucket] = (
-            jnp.concatenate(parts[bucket])
-            if len(parts[bucket]) > 1
-            else parts[bucket][0]
-        )
+    with jax.named_scope("pack"):
+        for slot, leaf in zip(spec.slots, leaves):
+            parts[slot.bucket].append(jnp.asarray(leaf).reshape(-1))
+            used[slot.bucket] += slot.size
+        for bucket in spec.buckets:
+            pad = spec.padded_size(bucket) - used[bucket]
+            if pad:
+                parts[bucket].append(jnp.zeros((pad,), dtype=jnp.dtype(bucket)))
+            out[bucket] = (
+                jnp.concatenate(parts[bucket])
+                if len(parts[bucket]) > 1
+                else parts[bucket][0]
+            )
     return out
 
 
 def unflatten_pytree(spec: FlatSpec, buffers: Dict[str, jax.Array]) -> Any:
-    """Inverse of :func:`flatten_pytree` (static slices — free at trace time)."""
+    """Inverse of :func:`flatten_pytree` (static slices — free at trace
+    time), under the ``unpack`` name scope."""
     leaves = []
-    for slot in spec.slots:
-        buf = buffers[slot.bucket]
-        leaves.append(buf[slot.offset : slot.offset + slot.size].reshape(slot.shape))
+    with jax.named_scope("unpack"):
+        for slot in spec.slots:
+            buf = buffers[slot.bucket]
+            leaves.append(
+                buf[slot.offset : slot.offset + slot.size].reshape(slot.shape)
+            )
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
@@ -239,35 +245,40 @@ def _resolve_impl(impl: str) -> str:
 
 
 def _quantize(x32, block: int, impl: str):
-    if impl == "ref":
-        return q_ref.quantize_ref(x32, block=block)
-    return q_kernel.quantize_fwd(
-        x32, block=block, interpret=(impl == "pallas_interpret")
-    )
+    with jax.named_scope("quantize"):
+        if impl == "ref":
+            return q_ref.quantize_ref(x32, block=block)
+        return q_kernel.quantize_fwd(
+            x32, block=block, interpret=(impl == "pallas_interpret")
+        )
 
 
 def _dequant_acc(q, s, acc, w, block: int, impl: str):
-    if impl == "ref":
-        return q_ref.dequant_acc_ref(q, s, acc, w, block=block)
-    return q_kernel.dequant_accumulate_fwd(
-        q, s, acc, w, block=block, interpret=(impl == "pallas_interpret")
-    )
+    with jax.named_scope("dequant_acc"):
+        if impl == "ref":
+            return q_ref.dequant_acc_ref(q, s, acc, w, block=block)
+        return q_kernel.dequant_accumulate_fwd(
+            q, s, acc, w, block=block, interpret=(impl == "pallas_interpret")
+        )
 
 
 def _topk(x32, k: int, block: int, impl: str):
-    if impl == "ref":
-        return q_ref.topk_sparsify_ref(x32, k, block=block)
-    return q_kernel.topk_sparsify_fwd(
-        x32, k, block=block, interpret=(impl == "pallas_interpret")
-    )
+    with jax.named_scope("topk"):
+        if impl == "ref":
+            return q_ref.topk_sparsify_ref(x32, k, block=block)
+        return q_kernel.topk_sparsify_fwd(
+            x32, k, block=block, interpret=(impl == "pallas_interpret")
+        )
 
 
 def _scatter_acc(vals, idxs, acc, w, block: int, impl: str):
-    if impl == "ref":
-        return q_ref.scatter_acc_ref(vals, idxs, acc, w, block=block)
-    return q_kernel.scatter_accumulate_fwd(
-        vals, idxs, acc, w, block=block, interpret=(impl == "pallas_interpret")
-    )
+    with jax.named_scope("scatter_acc"):
+        if impl == "ref":
+            return q_ref.scatter_acc_ref(vals, idxs, acc, w, block=block)
+        return q_kernel.scatter_accumulate_fwd(
+            vals, idxs, acc, w, block=block,
+            interpret=(impl == "pallas_interpret"),
+        )
 
 
 def int8_gossip(
@@ -303,8 +314,9 @@ def int8_gossip(
         s_r = tdm.exchange_matching(scales, m, axis_name)
         w = jnp.asarray(w_m, jnp.float32)[idx]
         acc = _dequant_acc(q_r, s_r, acc, w, block, impl)
-    self_w = jnp.asarray(diag, jnp.float32)[idx]
-    return (self_w * x32 + acc).astype(x.dtype)
+    with jax.named_scope("mix"):
+        self_w = jnp.asarray(diag, jnp.float32)[idx]
+        return (self_w * x32 + acc).astype(x.dtype)
 
 
 def choco_fused_round(
@@ -371,9 +383,10 @@ def choco_fused_round(
     deg_w = np.zeros((n,), dtype=np.float32)
     for i in range(n):
         deg_w[i] = sum(W[i, j] for j in rel.peers_of(i))
-    d_i = jnp.asarray(deg_w, jnp.float32)[idx]
-    new_x = x32 + jnp.float32(gamma) * (s - d_i * new_x_hat)
-    return new_x.astype(buf.dtype), tdm.ChocoState(x_hat=new_x_hat, s=s)
+    with jax.named_scope("mix"):
+        d_i = jnp.asarray(deg_w, jnp.float32)[idx]
+        new_x = x32 + jnp.float32(gamma) * (s - d_i * new_x_hat)
+        return new_x.astype(buf.dtype), tdm.ChocoState(x_hat=new_x_hat, s=s)
 
 
 def mix_wire_bytes(

@@ -74,11 +74,13 @@ def peer_slot_table(rel: Relation, n: int) -> Tuple[np.ndarray, List[Relation]]:
 # ---------------------------------------------------------------------------
 
 def exchange_matching(x: jax.Array, matching: Relation, axis_name: str) -> jax.Array:
-    """One pairwise exchange round: ppermute along the node axis."""
+    """One pairwise exchange round: ppermute along the node axis, under the
+    ``permute`` name scope."""
     perm = matching_permutation(matching)
     if not perm:
         return jnp.zeros_like(x)
-    return jax.lax.ppermute(x, axis_name, perm)
+    with jax.named_scope("permute"):
+        return jax.lax.ppermute(x, axis_name, perm)
 
 
 def get_meas(
@@ -185,10 +187,11 @@ def gossip_avg(
     """
     diag, per_matching = matching_weight_vectors(rel, n)
     idx = jax.lax.axis_index(axis_name)
-    out = jnp.asarray(diag, dtype=x.dtype)[idx] * x
-    for m, w_m in zip(edge_coloring(rel), per_matching):
-        recv = exchange_matching(x, m, axis_name)
-        out = out + jnp.asarray(w_m, dtype=x.dtype)[idx] * recv
+    with jax.named_scope("mix"):
+        out = jnp.asarray(diag, dtype=x.dtype)[idx] * x
+        for m, w_m in zip(edge_coloring(rel), per_matching):
+            recv = exchange_matching(x, m, axis_name)
+            out = out + jnp.asarray(w_m, dtype=x.dtype)[idx] * recv
     return out
 
 

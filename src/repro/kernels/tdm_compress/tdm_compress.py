@@ -71,9 +71,14 @@ def _slabs(x: jax.Array, block: int) -> jax.Array:
 
 
 def _tiled_call(
-    kernel, nb: int, block: int, in_widths, out_shapes, interpret, aliases=None
+    kernel, name: str, nb: int, block: int, in_widths, out_shapes, interpret,
+    aliases=None,
 ):
     """``pallas_call`` over per-block operands tiled ``rows`` blocks at a time.
+
+    ``name`` is the kernel's stable name in compiled programs and profiles
+    (``tdm_quantize``, ``tdm_dequant_acc``, ...), taken from its public
+    entry point, so a trace finds the kernel by name after any refactor.
 
     ``in_widths``/``out_shapes`` describe each operand: ``"slab"`` for the
     ``(nb, sub, lanes)`` payload view, an int ``w`` for a lane-dense
@@ -104,6 +109,7 @@ def _tiled_call(
         out_shape=[shape(w, d) for w, d in out_shapes],
         input_output_aliases=aliases or {},
         interpret=interpret,
+        name=name,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
     )
 
@@ -226,7 +232,7 @@ def quantize_fwd(x: jax.Array, *, block: int = 1024, interpret: bool = False):
     x = _pad_to_block(x, block)
     nb = x.shape[0] // block
     q, s = _tiled_call(
-        _quant_kernel, nb, block, ["slab"],
+        _quant_kernel, "tdm_quantize", nb, block, ["slab"],
         [("slab", jnp.int8), (1, jnp.float32)],
         interpret,
     )(_slabs(x, block))
@@ -241,7 +247,8 @@ def dequantize_fwd(q: jax.Array, scales: jax.Array, *, block: int = 1024,
     nb = q.shape[0] // block
     assert scales.shape[0] == nb, (scales.shape, nb, block)
     (x,) = _tiled_call(
-        _dequant_kernel, nb, block, ["slab", 1], [("slab", jnp.float32)],
+        _dequant_kernel, "tdm_dequantize", nb, block, ["slab", 1],
+        [("slab", jnp.float32)],
         interpret,
     )(_slabs(q, block), scales.reshape(1, nb))
     return x.reshape(nb * block)[:n]
@@ -270,7 +277,8 @@ def dequant_accumulate_fwd(
     assert scales.shape[0] == nb, (scales.shape, nb, block)
     w2 = jnp.asarray(w, jnp.float32).reshape(1, 1)
     (out,) = _tiled_call(
-        _dequant_acc_kernel, nb, block, ["slab", 1, "slab", None],
+        _dequant_acc_kernel, "tdm_dequant_acc", nb, block,
+        ["slab", 1, "slab", None],
         [("slab", jnp.float32)],
         interpret,
         aliases={2: 0},
@@ -298,7 +306,8 @@ def quantize_scaled_fwd(
     nb = x.shape[0] // block
     assert scales.shape[0] == nb, (scales.shape, nb, block)
     (q,) = _tiled_call(
-        _quant_scaled_kernel, nb, block, ["slab", 1], [("slab", jnp.int8)],
+        _quant_scaled_kernel, "tdm_quantize_scaled", nb, block, ["slab", 1],
+        [("slab", jnp.int8)],
         interpret,
     )(_slabs(x, block), scales.reshape(1, nb))
     return q.reshape(nb * block)[:n]
@@ -332,7 +341,7 @@ def topk_sparsify_fwd(
             jnp.zeros((nb, 0), jnp.int32),
         )
     dense, vals, idxs = _tiled_call(
-        functools.partial(_topk_kernel, k), nb, block, ["slab"],
+        functools.partial(_topk_kernel, k), "tdm_topk", nb, block, ["slab"],
         [("slab", jnp.float32), (k, jnp.float32), (k, jnp.int32)],
         interpret,
     )(_slabs(x, block))
@@ -366,7 +375,8 @@ def scatter_accumulate_fwd(
         return acc.reshape(nb * block)[:n]
     w2 = jnp.asarray(w, jnp.float32).reshape(1, 1)
     (out,) = _tiled_call(
-        _scatter_acc_kernel, nb, block, [k, k, "slab", None],
+        _scatter_acc_kernel, "tdm_scatter_acc", nb, block,
+        [k, k, "slab", None],
         [("slab", jnp.float32)],
         interpret,
         aliases={2: 0},

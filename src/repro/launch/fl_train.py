@@ -77,6 +77,29 @@ def _stack_init(
     return jax.jit(init, out_shardings=NamedSharding(mesh, P(axis)))(key)
 
 
+def _local_steps(loss_fn, opt_cfg: adamw.OptConfig, state, batch, n_steps: int):
+    """``n_steps`` AdamW steps of one node on its own batches (leading axis
+    = step). Each step's forward, loss and backward run under the
+    ``local_step`` name scope, its clipping and update under ``optimizer``,
+    so a profile attributes the round's device time to them by name.
+    Returns ``(state, mean loss)``."""
+    losses = []
+    for h in range(n_steps):
+        with jax.named_scope("local_step"):
+            mb = jax.tree.map(lambda x: x[h], batch)
+            (loss, _), grads = jax.value_and_grad(
+                lambda p: loss_fn(p, mb), has_aux=True
+            )(state["params"])
+        with jax.named_scope("optimizer"):
+            new_p, new_opt, _ = adamw.apply_updates(
+                state["params"], grads, state["opt"], opt_cfg
+            )
+            state = {"params": new_p, "opt": new_opt, "step": state["step"] + 1}
+        losses.append(loss)
+    with jax.named_scope("local_step"):
+        return state, jnp.stack(losses).mean()
+
+
 def build_fl_round(
     cfg: ModelConfig,
     opt_cfg: adamw.OptConfig,
@@ -102,30 +125,19 @@ def build_fl_round(
         state = jax.tree.map(lambda x: x[0], state)
         batch = jax.tree.map(lambda x: x[0], batch)
 
-        def one_step(st, mb):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: b.loss_fn(p, mb), has_aux=True
-            )(st["params"])
-            new_p, new_opt, _ = adamw.apply_updates(
-                st["params"], grads, st["opt"], opt_cfg
-            )
-            return {"params": new_p, "opt": new_opt, "step": st["step"] + 1}, loss
-
-        losses = []
-        for h in range(fl_cfg.local_steps):
-            mb = jax.tree.map(lambda x: x[h], batch)
-            state, loss = one_step(state, mb)
-            losses.append(loss)
-        local_loss = jnp.stack(losses).mean()
+        state, local_loss = _local_steps(
+            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
+        )
 
         # ---- the paper's communication step
         params = state["params"]
-        if fl_cfg.mode == "centralized":
-            params = fl.centralized_round(params, axis)
-        elif fl_cfg.mode == "decentralized":
-            params = fl.decentralized_round(params, axis, n_nodes)
-        else:
-            params, _ = fl.tdm_fla_round(params, rel, axis, n_nodes, tdm_cfg)
+        with jax.named_scope("exchange"):
+            if fl_cfg.mode == "centralized":
+                params = fl.centralized_round(params, axis)
+            elif fl_cfg.mode == "decentralized":
+                params = fl.decentralized_round(params, axis, n_nodes)
+            else:
+                params, _ = fl.tdm_fla_round(params, rel, axis, n_nodes, tdm_cfg)
         state = dict(state, params=params)
 
         state = jax.tree.map(lambda x: x[None], state)
@@ -181,32 +193,21 @@ def build_hierarchical_fl_round(
         state = jax.tree.map(lambda x: x[0], state)
         batch = jax.tree.map(lambda x: x[0], batch)
 
-        def one_step(st, mb):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: b.loss_fn(p, mb), has_aux=True
-            )(st["params"])
-            new_p, new_opt, _ = adamw.apply_updates(
-                st["params"], grads, st["opt"], opt_cfg
-            )
-            return {"params": new_p, "opt": new_opt, "step": st["step"] + 1}, loss
-
-        losses = []
-        for h in range(fl_cfg.local_steps):
-            mb = jax.tree.map(lambda x: x[h], batch)
-            state, loss = one_step(state, mb)
-            losses.append(loss)
-        local_loss = jnp.stack(losses).mean()
-
-        params = fused_lib.fused_hierarchical_round(
-            state["params"],
-            intra_rel,
-            inter_rel,
-            data_axis,
-            pod_axis,
-            n_data,
-            n_pods,
-            compression=fl_cfg.compression,
+        state, local_loss = _local_steps(
+            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
         )
+
+        with jax.named_scope("exchange"):
+            params = fused_lib.fused_hierarchical_round(
+                state["params"],
+                intra_rel,
+                inter_rel,
+                data_axis,
+                pod_axis,
+                n_data,
+                n_pods,
+                compression=fl_cfg.compression,
+            )
         state = dict(state, params=params)
 
         state = jax.tree.map(lambda x: x[None], state)
@@ -343,10 +344,13 @@ def run_tdm_rounds(
 
     Telemetry: every round bumps default-on flight-recorder counters
     (``fl.rounds``, cache hit/miss, the oracle's per-round collective
-    counts) — host-side dict updates only, no extra device syncs. With
-    tracing on, each round also records a ``cat="slot"`` span whose wall
-    time is made accurate by a ``block_until_ready`` sync (tracing-only,
-    so untraced runs stay async-dispatchable).
+    counts) — host-side dict updates only, no extra device syncs. Each
+    round runs inside an ``fl.round`` span, which a profiler session sees
+    on the host plane and which the recorder keeps (``cat="slot"``) with
+    tracing on. The span times the host's dispatch of the round, never the
+    device's work: nothing waits for the device, traced or not, so rounds
+    stay async-dispatchable. Per-round device time is read from the
+    profiler's device plane.
     """
     rec = telemetry.get_recorder()
     n_nodes = cache.n_nodes
@@ -367,8 +371,6 @@ def run_tdm_rounds(
                 example_args=(state, batch) if rec.reconcile else None,
             )
             state, losses = fn(state, batch)
-            if rec.tracing:
-                jax.block_until_ready((state, losses))
         rec.counter("fl.rounds")
         expected = cache.expected_collectives(rel_t, state)
         if expected:
@@ -569,37 +571,25 @@ def build_groundseg_round(
         idx = jax.lax.axis_index(axis)
         is_sink = jnp.asarray(sink_mask)[idx]
 
-        def one_step(st, mb):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: b.loss_fn(p, mb), has_aux=True
-            )(st["params"])
-            new_p, new_opt, _ = adamw.apply_updates(
-                st["params"], grads, st["opt"], opt_cfg
-            )
-            return {"params": new_p, "opt": new_opt, "step": st["step"] + 1}, loss
-
-        trained = state
-        losses = []
-        for h in range(fl_cfg.local_steps):
-            mb = jax.tree.map(lambda x: x[h], batch)
-            trained, loss = one_step(trained, mb)
-            losses.append(loss)
-        local_loss = jnp.stack(losses).mean()
+        trained, local_loss = _local_steps(
+            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
+        )
         # sinks are aggregation infrastructure, not learners
         state = jax.tree.map(
             lambda new, old: jnp.where(is_sink, old, new), trained, state
         )
 
-        params = aggregation.groundseg_round(
-            state["params"],
-            uplink,
-            downlink,
-            axis,
-            pool=pool,
-            compression=gs_cfg.compression,
-            block=gs_cfg.block,
-            quant_impl=gs_cfg.quant_impl,
-        )
+        with jax.named_scope("exchange"):
+            params = aggregation.groundseg_round(
+                state["params"],
+                uplink,
+                downlink,
+                axis,
+                pool=pool,
+                compression=gs_cfg.compression,
+                block=gs_cfg.block,
+                quant_impl=gs_cfg.quant_impl,
+            )
         state = dict(state, params=params)
 
         state = jax.tree.map(lambda x: x[None], state)
@@ -646,38 +636,26 @@ def build_pipelined_groundseg_round(
         idx = jax.lax.axis_index(axis)
         is_sink = jnp.asarray(sink_mask)[idx]
 
-        def one_step(st, mb):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: b.loss_fn(p, mb), has_aux=True
-            )(st["params"])
-            new_p, new_opt, _ = adamw.apply_updates(
-                st["params"], grads, st["opt"], opt_cfg
-            )
-            return {"params": new_p, "opt": new_opt, "step": st["step"] + 1}, loss
-
-        trained = state
-        losses = []
-        for h in range(fl_cfg.local_steps):
-            mb = jax.tree.map(lambda x: x[h], batch)
-            trained, loss = one_step(trained, mb)
-            losses.append(loss)
-        local_loss = jnp.stack(losses).mean()
+        trained, local_loss = _local_steps(
+            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
+        )
         state = jax.tree.map(
             lambda new, old: jnp.where(is_sink, old, new), trained, state
         )
 
-        params, carry, pending = aggregation.pipelined_window_round(
-            state["params"],
-            aux["carry"],
-            aux["pending"],
-            wp,
-            axis,
-            pool=pool,
-            staleness_decay=gs_cfg.staleness_decay,
-            compression=gs_cfg.compression,
-            block=gs_cfg.block,
-            quant_impl=gs_cfg.quant_impl,
-        )
+        with jax.named_scope("exchange"):
+            params, carry, pending = aggregation.pipelined_window_round(
+                state["params"],
+                aux["carry"],
+                aux["pending"],
+                wp,
+                axis,
+                pool=pool,
+                staleness_decay=gs_cfg.staleness_decay,
+                compression=gs_cfg.compression,
+                block=gs_cfg.block,
+                quant_impl=gs_cfg.quant_impl,
+            )
         state = dict(state, params=params)
         aux = {"carry": carry, "pending": pending}
 
@@ -860,8 +838,6 @@ def run_groundseg_fl(
             unreachable=len(up.unreachable),
         ):
             state, losses = fn(state, batch)
-            if rec.tracing:
-                jax.block_until_ready((state, losses))
         rec.counter("groundseg.rounds")
         rec.counter("groundseg.payloads.delivered", up.delivered_count())
         rec.counter("groundseg.payloads.unreachable", len(up.unreachable))
@@ -988,8 +964,6 @@ def _run_groundseg_pipelined(
             dropped=len(wp.dropped),
         ):
             state, aux, losses = fn_cache[key](state, aux, batch)
-            if rec.tracing:
-                jax.block_until_ready((state, losses))
         # payload lifecycle: queued -> relayed -> delivered | carried |
         # dropped. Counters are default-on; per-payload instants (with
         # staleness ages) exist only while tracing.

@@ -14,8 +14,10 @@ Quick use::
         telemetry.write_report("mission", rec)          # -> .md + .json
 
 Counters, gauges, and histograms are default-on (host-side dict/bisect
-work, zero device syncs); spans, events, and per-round
-``block_until_ready`` wall-clock timing exist only under ``tracing=True``;
+work, zero device syncs); spans and events are kept only under
+``tracing=True``, and every span is also a ``jax.profiler`` annotation on
+the profiler's host plane (nothing syncs with the device: per-round device
+time comes from the profiler's device plane);
 ``reconcile=True`` verifies every newly compiled round/window against the
 static collective oracles. :func:`audit_window_programs` replays a planned
 window sequence hop by hop and returns a structured verdict.

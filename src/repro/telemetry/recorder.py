@@ -16,25 +16,33 @@ Contract (verified by ``tests/_telemetry_worker.py`` on 8 devices):
   telemetry disabled the compiled programs and their outputs are
   bit-identical to an uninstrumented build, and the driver loops issue
   ZERO additional host syncs.
-- **Spans and events exist only while tracing is on.** Accurate per-round
-  wall time needs a ``block_until_ready`` host sync, and per-payload
-  lifecycle events are unbounded over a long run — both are opt-in via
-  :func:`set_tracing` / ``record_scope(tracing=True)``. With tracing off,
-  :meth:`Recorder.span` is a no-op context manager that records nothing
-  and takes no timestamps.
+- **Spans and events are kept only while tracing is on.** Per-payload
+  lifecycle events are unbounded over a long run, so keeping them is
+  opt-in via :func:`set_tracing` / ``record_scope(tracing=True)``. With
+  tracing off, :meth:`Recorder.span` keeps nothing and takes no
+  timestamps of its own.
+- **Spans are on the profiler's clock.** Once JAX is imported, every
+  :meth:`Recorder.span` also opens a ``jax.profiler.TraceAnnotation`` of
+  the same name, tracing on or off, so a profiler session shows the
+  program's spans (``fl.round``, ``groundseg.window``, ...) on its host
+  plane beside the device's ops. Without a session the annotation is a
+  near no-op. No span syncs with the device: a span around a dispatch
+  times the dispatch, and device time comes from the profiler.
 - **Recordings are scoped, not global.** :func:`record_scope` pushes a
   fresh :class:`Recorder` for one benchmark/test/training run and pops it
   after, so counters cannot leak across runs (the bug the old bare
   ``fused._SPEC_CACHE_STATS`` module dict had).
 
 The module is stdlib-only by design: :mod:`repro.core` imports it, so it
-must sit below everything jax-flavored in the dependency order.
+must sit below everything jax-flavored in the dependency order. It finds
+JAX's profiler through ``sys.modules`` and never imports JAX itself.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import sys
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
@@ -70,11 +78,19 @@ class Event:
     tid: int = 0
 
 
+def _profiler_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` when JAX is already imported,
+    else a null context (this module never imports JAX)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
 class Recorder:
     """A single flight recording: counters (always), spans/events (tracing).
 
-    ``tracing``   — record spans/events and permit host-sync timing in the
-                    instrumented drivers.
+    ``tracing``   — keep spans/events in memory.
     ``reconcile`` — production-assert mode: drivers verify each newly
                     compiled round/window against the static collective
                     oracles via :mod:`repro.telemetry.reconcile` (costs one
@@ -141,22 +157,24 @@ class Recorder:
         self, name: str, cat: str = "span", tid: int = 0, **args
     ) -> Iterator[Optional[Dict[str, Any]]]:
         """Time a block. Yields the (mutable) args dict so the body can
-        attach results; yields ``None`` and records nothing when tracing
-        is off."""
-        if not self.tracing:
-            yield None
-            return
-        t0 = self.now_us()
-        try:
-            yield args
-        finally:
-            self.spans.append(
-                Span(name, cat, t0, self.now_us() - t0, dict(args), tid)
-            )
-            if len(self.spans) > self.max_spans:
-                drop = len(self.spans) - self.max_spans
-                del self.spans[:drop]
-                self.counter("telemetry.dropped_spans", drop)
+        attach results; yields ``None`` and keeps nothing when tracing is
+        off. Either way the block runs inside a profiler annotation of
+        the same name once JAX is imported."""
+        with _profiler_annotation(name):
+            if not self.tracing:
+                yield None
+                return
+            t0 = self.now_us()
+            try:
+                yield args
+            finally:
+                self.spans.append(
+                    Span(name, cat, t0, self.now_us() - t0, dict(args), tid)
+                )
+                if len(self.spans) > self.max_spans:
+                    drop = len(self.spans) - self.max_spans
+                    del self.spans[:drop]
+                    self.counter("telemetry.dropped_spans", drop)
 
     # -- introspection ----------------------------------------------------
     def span_stats(self) -> Dict[str, Dict[str, float]]:

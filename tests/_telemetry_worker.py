@@ -120,7 +120,7 @@ def _n_buckets(state):
 
 # ---------------------------------------------------------------------------
 # 1. telemetry disabled: counters still collected, but ZERO extra host syncs
-#    (every block_until_ready is tracing-gated) and nothing traced
+#    (the FL run loops never block_until_ready) and nothing traced
 # ---------------------------------------------------------------------------
 def test_disabled_zero_host_syncs():
     gp, tp = groundseg_plan(), tdm_plan()

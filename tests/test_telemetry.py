@@ -1,11 +1,14 @@
 """Flight-recorder telemetry: recorder semantics, Chrome-trace schema,
-oracle reconciliation, router drop-log bounds, and the BENCH-summary
-plumbing — single-process tests plus the launcher for the multi-device
-worker (_telemetry_worker.py — subprocess, 8 forced host devices)."""
+oracle reconciliation, router drop-log bounds, the BENCH-summary plumbing,
+and the round's named scopes (read as ``bench/scopes.py`` reads them) and
+profiler-clock spans — single-process tests plus the launchers for the
+multi-device workers (_telemetry_worker.py — 8 forced host devices;
+_scope_worker.py — 2)."""
 
 import json
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -704,6 +707,131 @@ def test_check_regression_fails_when_nothing_compared(tmp_path, capsys):
     )
     capsys.readouterr()
     assert rc == 0
+
+
+# ------------------------------------- named scopes and profiler-clock spans
+# compiler-made fusions that carry no op_name of the program: a lone
+# instruction XLA wraps (``wrapped_*``), and the layout copies, bitcasts
+# and bf16 converts the CPU backend inserts
+_UNSCOPED_OK = re.compile(
+    r"^(wrapped_[\w-]+|((convert|copy|bitcast|transpose)_)+fusion)(\.\d+)?$"
+)
+
+
+def _carried_scopes(hlo_text):
+    """(instruction, scope, backward) of every fusion, custom-call and
+    collective-permute, read from its own op_name, or for a fusion without
+    one, from its fused instructions' op_names."""
+    from bench import scopes
+
+    by_comp, comp = {}, None
+    for line in hlo_text.splitlines():
+        m = scopes._COMPUTATION.match(line)
+        if m:
+            comp = by_comp.setdefault(m["name"], [])
+        elif comp is not None and 'op_name="' in line:
+            op_name = line.split('op_name="')[1].split('"')[0]
+            comp.append(scopes.parse_op_name(op_name))
+    out = []
+    for line in hlo_text.splitlines():
+        m = scopes._INSTR.match(line)
+        if m is None or not (
+            m["op"] in ("fusion", "custom-call")
+            or m["op"].startswith("collective-permute")
+        ):
+            continue
+        found = [
+            scopes.parse_op_name(x.split('"')[0])
+            for x in line.split('op_name="')[1:]
+        ]
+        if m["op"] == "fusion" and not found:
+            found = by_comp[re.search(r"calls=%([\w.\-]+)", line)[1]]
+        found = [f for f in found if f[0]]
+        out.append((m["name"],) + (found[0] if found else (None, False)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def scoped_round():
+    """Two int8 rounds of a 2-node ring, traced (``_scope_worker.py``)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"{ROOT / 'src'}:{ROOT / 'tests'}:" + env.get(
+        "PYTHONPATH", ""
+    )
+    proc = subprocess.run(
+        [sys.executable, str(ROOT / "tests" / "_scope_worker.py")],
+        capture_output=True,
+        text=True,
+        env=env,
+        timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_round_ops_carry_named_scopes(scoped_round):
+    carried = _carried_scopes(scoped_round["hlo"])
+    stray = [
+        n for n, scope, _ in carried if scope is None and not _UNSCOPED_OK.match(n)
+    ]
+    assert not stray, stray
+    seen = {(scope, backward) for _, scope, backward in carried}
+    # forward and backward of the local step, the optimizer, and the int8
+    # exchange's phases (on the CPU, dequant_acc and mix fuse into unpack)
+    for want in ("optimizer", "pack", "quantize", "permute", "unpack"):
+        assert (want, False) in seen, (want, seen)
+    assert {("local_step", False), ("local_step", True)} <= seen
+
+
+def test_traced_rounds_make_no_host_syncs(scoped_round):
+    assert scoped_round["syncs"] == 0
+    assert scoped_round["spans"].count("fl.round") == 2
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    """With tracing off the recorder keeps nothing, yet its span lands on
+    the profiler's host plane, inside the annotation that encloses it."""
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    rec = telemetry.Recorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench.window"):
+            with rec.span("fl.round", cat="slot") as sp:
+                jax.block_until_ready(jax.numpy.ones(4) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    assert sp is None and rec.spans == []
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    spans = {
+        e.name: (e.start_ns, e.start_ns + e.duration_ns)
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name in ("bench.window", "fl.round")
+    }
+    (w0, w1), (r0, r1) = spans["bench.window"], spans["fl.round"]
+    assert w0 <= r0 <= r1 <= w1
+
+
+def test_recorder_imports_without_jax():
+    code = (
+        "import sys\n"
+        "from repro.telemetry import recorder\n"
+        "rec = recorder.Recorder(tracing=True)\n"
+        "with rec.span('fl.round'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'recorder imported jax'\n"
+        "assert [s.name for s in rec.spans] == ['fl.round']\n"
+    )
+    env = dict(os.environ, PYTHONPATH=f"{ROOT / 'src'}")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env,
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 # ------------------------------------------------------- multidevice worker

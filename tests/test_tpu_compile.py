@@ -5,7 +5,8 @@ it cannot see what the TPU's Mosaic compiler refuses — tile shapes that
 break the (8, 128) / (32, 128) layout rules, or tiles that overflow VMEM.
 These tests lower every kernel at the length of one node's fused
 ``mamba2-780m`` buffer for a ``v5e:2x2`` topology that is described, not
-attached, and check that the kernel survived as a ``tpu_custom_call``.
+attached, and check that the kernel survived as a ``tpu_custom_call``
+named after its entry point (``tdm_quantize``, ...), as a trace shows it.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
@@ -61,14 +62,17 @@ def _arg(shape, dtype, sharding):
 
 CASES = {
     "quantize": (
+        "tdm_quantize",
         functools.partial(kern.quantize_fwd, block=BLOCK),
         [((N_ELEMS,), jnp.float32)],
     ),
     "dequantize": (
+        "tdm_dequantize",
         functools.partial(kern.dequantize_fwd, block=BLOCK),
         [((N_ELEMS,), jnp.int8), ((NB,), jnp.float32)],
     ),
     "dequant_accumulate": (
+        "tdm_dequant_acc",
         functools.partial(kern.dequant_accumulate_fwd, block=BLOCK),
         [
             ((N_ELEMS,), jnp.int8),
@@ -78,14 +82,17 @@ CASES = {
         ],
     ),
     "quantize_scaled": (
+        "tdm_quantize_scaled",
         functools.partial(kern.quantize_scaled_fwd, block=BLOCK),
         [((N_ELEMS,), jnp.float32), ((NB,), jnp.float32)],
     ),
     "topk_sparsify": (
+        "tdm_topk",
         functools.partial(kern.topk_sparsify_fwd, k=K, block=BLOCK),
         [((N_ELEMS,), jnp.float32)],
     ),
     "scatter_accumulate": (
+        "tdm_scatter_acc",
         functools.partial(kern.scatter_accumulate_fwd, block=BLOCK),
         [
             ((NB, K), jnp.float32),
@@ -99,7 +106,11 @@ CASES = {
 
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
-    fn, shapes = CASES[name]
+    kernel, fn, shapes = CASES[name]
     args = [_arg(s, d, one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    # the kernel is named after its entry point, in the custom-call's
+    # instruction name and in its op_name: what a trace shows of it
+    (call,) = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    assert call.split("=")[0].split()[-1].lstrip("%").split(".")[0] == kernel
+    assert f"/{kernel}/pallas_call" in call
