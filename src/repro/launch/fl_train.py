@@ -36,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import telemetry
 from repro.core import fl, tdm
 from repro.core.relation import Relation
+from repro.launch import flops
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.optim import adamw
@@ -98,6 +99,25 @@ def _local_steps(loss_fn, opt_cfg: adamw.OptConfig, state, batch, n_steps: int):
         losses.append(loss)
     with jax.named_scope("local_step"):
         return state, jnp.stack(losses).mean()
+
+
+def _record_remat_saved_bytes(cfg: ModelConfig, state, batch, rec) -> None:
+    """Gauge ``fl.remat_saved_bytes``: the bytes one local step's forward
+    keeps for its backward by remat policy (a Mamba-2 layer's saved
+    in-projections and SSD output; 0 for a model without Mamba layers),
+    per node, from one node's abstract shapes. Costs one trace of the
+    loss, so it is recorded on a round-cache miss with tracing on only."""
+    if not rec.tracing:
+        return
+    loss_fn = registry.bundle(cfg).loss_fn
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), state["params"]
+    )
+    step_batch = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape[2:], x.dtype), batch
+    )
+    saved = flops.remat_saved_bytes(lambda p, b: loss_fn(p, b)[0], params, step_batch)
+    telemetry.set_gauge("fl.remat_saved_bytes", saved, rec=rec)
 
 
 def build_fl_round(
@@ -292,6 +312,8 @@ class RoundFnCache:
                 cache_size=len(self._fns),
             )
             fn = build_fl_round(*self.args, rel, axis=self.axis)
+            if example_args is not None:
+                _record_remat_saved_bytes(self.args[0], *example_args, rec)
             if rec.reconcile and example_args is not None:
                 with rec.span("fl.compile", cat="compile", links=len(rel) // 2):
                     fn = telemetry.compile_and_check(
@@ -344,7 +366,9 @@ def run_tdm_rounds(
 
     Telemetry: every round bumps default-on flight-recorder counters
     (``fl.rounds``, cache hit/miss, the oracle's per-round collective
-    counts) — host-side dict updates only, no extra device syncs. Each
+    counts) — host-side dict updates only, no extra device syncs. With
+    tracing on, each compiled round also sets the gauge
+    ``fl.remat_saved_bytes`` from abstract shapes. Each
     round runs inside an ``fl.round`` span, which a profiler session sees
     on the host plane and which the recorder keeps (``cat="slot"``) with
     tracing on. The span times the host's dispatch of the round, never the
@@ -368,7 +392,9 @@ def run_tdm_rounds(
         ):
             fn = cache(
                 rel_t,
-                example_args=(state, batch) if rec.reconcile else None,
+                example_args=(
+                    (state, batch) if rec.reconcile or rec.tracing else None
+                ),
             )
             state, losses = fn(state, batch)
         rec.counter("fl.rounds")
@@ -815,6 +841,7 @@ def run_groundseg_fl(
             fn = build_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, up, down, pool
             )
+            _record_remat_saved_bytes(cfg, state, batch, rec)
             if rec.reconcile:
                 with rec.span("groundseg.compile", cat="compile", pool=pool):
                     fn = telemetry.compile_and_check(
@@ -940,6 +967,7 @@ def _run_groundseg_pipelined(
             fn = build_pipelined_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, wp, pool
             )
+            _record_remat_saved_bytes(cfg, state, batch, rec)
             if rec.reconcile:
                 with rec.span("groundseg.compile", cat="compile", pool=pool):
                     fn = telemetry.compile_and_check(

@@ -27,6 +27,7 @@ from dataclasses import dataclass
 
 import jax
 from jax import core as jcore
+from jax.ad_checkpoint import Saveable
 
 
 ELEMENTWISE = {
@@ -193,3 +194,33 @@ def program_costs(fn, *args, **kwargs) -> Costs:
     io_bytes += sum(_nbytes(v.aval) for v in closed.jaxpr.outvars)
     c.traffic_bytes += io_bytes
     return c
+
+
+def remat_saved_bytes(fn, *args, **kwargs) -> float:
+    """Bytes that the remat policies in ``fn(*args)``'s forward keep for the
+    backward, beyond each checkpoint's inputs: the outputs of the
+    equations a checkpoint's policy marks saveable, times the trip counts
+    of the scans around them. A checkpoint without a policy keeps nothing
+    more, and a model outside any checkpoint reads 0."""
+    return _saved_bytes(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr, None)
+
+
+def _saved_bytes(jaxpr: jcore.Jaxpr, policy) -> float:
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        prim = eqn.primitive.name
+        if prim == "scan":
+            total += eqn.params["length"] * _saved_bytes(eqn.params["jaxpr"].jaxpr, policy)
+            continue
+        if prim == "remat2":  # jax.checkpoint
+            total += _saved_bytes(eqn.params["jaxpr"], eqn.params["policy"])
+            continue
+        sub = next((eqn.params[n] for n in CALL_PARAM_NAMES if n in eqn.params), None)
+        if sub is not None:
+            total += _saved_bytes(getattr(sub, "jaxpr", sub), policy)
+            continue
+        if policy is not None:
+            saved = policy(eqn.primitive, *(v.aval for v in eqn.invars), **eqn.params)
+            if saved is True or saved is Saveable:
+                total += sum(_nbytes(v.aval) for v in eqn.outvars)
+    return total

@@ -19,10 +19,21 @@ from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.launch.sharding import shard_activation
 from repro.models.config import ModelConfig
 from repro.models.layers import dtype_of, rmsnorm, truncated_normal
+
+
+# Name of the values a training layer keeps for its backward: the
+# in-projection outputs (z, x, B, C, dt) and the SSD scan's output y. The
+# layer checkpoint of ``transformer.forward_train`` saves the values
+# carrying it, so the backward reads them instead of recomputing the
+# projection matmuls and the scan's quadratic chunk work; the scan's own
+# backward recomputes each chunk once (``ssd_chunked(remat_body=True)``).
+# Outside a checkpoint (serving) the name is the identity.
+SAVED = "mamba_saved"
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +145,7 @@ def _project(p: Dict, x: jax.Array, cfg: ModelConfig):
     Bv = shard_activation(Bv, ("batch", "seq", None, None))
     Cv = shard_activation(Cv, ("batch", "seq", None, None))
     dt_raw = shard_activation(dt_raw, ("batch", "seq", "mamba_heads"))
-    return z, xc, Bv, Cv, dt_raw
+    return tuple(checkpoint_name(t, SAVED) for t in (z, xc, Bv, Cv, dt_raw))
 
 
 def _conv_mix(p, xc, Bv, Cv, cfg: ModelConfig):
@@ -267,6 +278,7 @@ def mamba_forward(p: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     y, _ = ssd_chunked(
         xh, dt, A, Bv, Cv, chunk, remat_body=cfg.remat != "none"
     )
+    y = checkpoint_name(y, SAVED)
     y = y + xh * p["D_skip"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B_, S, di)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm"], cfg.norm_eps)

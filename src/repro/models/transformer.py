@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -328,6 +328,18 @@ def init_encoder(key, cfg: ModelConfig) -> Tuple[Params, Dict]:
     return p, s
 
 
+def remat_layer(body: Callable, cfg: ModelConfig) -> Callable:
+    """A layer scan's body under ``cfg.remat``. With ``full`` the backward
+    recomputes the layer's forward from its input and the values saved by
+    name (``mamba2.SAVED``: a Mamba-2 mixer's in-projections and SSD
+    output); a layer that names nothing saves only its input."""
+    if cfg.remat != "full":
+        return body
+    return jax.checkpoint(
+        body, policy=jax.checkpoint_policies.save_only_these_names(mamba_lib.SAVED)
+    )
+
+
 # ---------------------------------------------------------------------------
 # encoder forward (whisper)
 # ---------------------------------------------------------------------------
@@ -350,8 +362,7 @@ def encoder_forward(params: Params, enc_embeds: jax.Array, cfg: ModelConfig) -> 
         h = h + mlp_apply(p["ffn"], hn, cfg)
         return h, None
 
-    fn = jax.checkpoint(body) if cfg.remat == "full" else body
-    h, _ = jax.lax.scan(fn, h, params["blocks"])
+    h, _ = jax.lax.scan(remat_layer(body, cfg), h, params["blocks"])
     return rmsnorm(h, params["final_ln"], cfg.norm_eps)
 
 
@@ -459,7 +470,7 @@ def forward_train(
         aux = {k_: aux[k_] + a[k_] for k_ in aux}
         return (h, aux), None
 
-    fn = jax.checkpoint(body) if cfg.remat == "full" else body
+    fn = remat_layer(body, cfg)
     aux0 = {
         "moe_aux": jnp.zeros((), jnp.float32),
         "moe_zloss": jnp.zeros((), jnp.float32),

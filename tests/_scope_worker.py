@@ -2,9 +2,9 @@
 devices: two int8 TDM rounds of a 2-node ring through ``run_tdm_rounds``
 with tracing and reconcile on, counting ``jax.block_until_ready`` calls.
 Prints one JSON line: the compiled round's HLO text (reconcile mode keeps
-the AOT-compiled executable in the round cache), the sync count and the
-names of the spans recorded. Launched as a subprocess (the device count
-locks at the first jax init).
+the AOT-compiled executable in the round cache), the sync count, the names
+of the spans recorded and the recorder's gauges. Launched as a subprocess
+(the device count locks at the first jax init).
 """
 
 import os
@@ -63,8 +63,11 @@ def main():
             jax.block_until_ready = orig
         jax.block_until_ready(state)
         spans = [s.name for s in rec.spans]
+        gauges = telemetry.metrics_snapshot(rec)["gauges"]
     (compiled,) = cache._fns.values()
-    print(json.dumps({"hlo": compiled.as_text(), "syncs": len(syncs), "spans": spans}))
+    print(json.dumps({
+        "hlo": compiled.as_text(), "syncs": len(syncs), "spans": spans, "gauges": gauges,
+    }))
 
 
 if __name__ == "__main__":

@@ -788,6 +788,21 @@ def test_traced_rounds_make_no_host_syncs(scoped_round):
     assert scoped_round["spans"].count("fl.round") == 2
 
 
+def test_traced_rounds_record_remat_saved_bytes(scoped_round):
+    """The round's smoke Mamba-2 keeps its five in-projections and its SSD
+    output per layer (bf16, 2x32 tokens a node) for the backward, and
+    nothing else."""
+    from repro.configs import archs
+
+    cfg = archs.smoke_cfg(archs.get("mamba2-780m"))
+    mb = cfg.mamba
+    per_token = (
+        3 * mb.d_inner(cfg.d_model) + 2 * mb.n_groups * mb.d_state + mb.n_heads(cfg.d_model)
+    )
+    expected = cfg.n_layers * 2 * 32 * per_token * 2
+    assert scoped_round["gauges"]["fl.remat_saved_bytes"] == expected
+
+
 def test_spans_are_profiler_annotations(tmp_path):
     """With tracing off the recorder keeps nothing, yet its span lands on
     the profiler's host plane, inside the annotation that encloses it."""
