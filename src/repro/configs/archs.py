@@ -1,4 +1,4 @@
-"""The 10 assigned architectures as exact configs, plus reduced smoke
+"""The assigned architectures as exact configs, plus reduced smoke
 variants of each family.
 
 Sources as assigned (``[source; tier]`` from the task sheet). Head dims use
@@ -12,6 +12,7 @@ the roofline requires at 256–512 chips; they are hillclimb levers in §Perf.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Optional
 
 from repro.models.config import MambaConfig, ModelConfig, MoEConfig, ShapeConfig, SHAPES
@@ -174,6 +175,30 @@ KIMI_K2_1T = _register(ModelConfig(
     serve_parallel_mode="tp2d",
 ))
 
+# --- nemotron-3-nano-30b-a3b [hybrid] 52 single-mixer layers d=2688 ---------
+# 23 Mamba-2 (64 heads x 64, 8 groups) + 23 MoE (128 experts top-6, sigmoid
+# router, relu2, one shared expert) + 6 NoPE GQA (32/2 x 128)
+# [hf:nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 config.json]
+NEMOTRON_3_NANO = _register(ModelConfig(
+    name="nemotron-3-nano-30b-a3b",
+    family="hybrid",
+    n_layers=52,
+    layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    d_model=2688,
+    n_heads=32, n_kv_heads=2, head_dim=128,
+    d_ff=1856,
+    vocab_size=131_072,
+    rope_theta=None,
+    mamba=MambaConfig(d_state=128, head_dim=64, heads=64, n_groups=8, chunk=128),
+    moe=MoEConfig(
+        n_experts=128, top_k=6, d_ff=1856, dispatch="dropless", routed_scale=2.5, shared_d_ff=3712, router_z_loss=0.0, aux_loss=0.0,
+    ),
+    act="relu2",
+    gated_mlp=False,
+    tie_embeddings=False,
+    norm_eps=1e-5,
+))
+
 # --- whisper-base [audio] 6L(+6 enc) d=512 8H ff=2048 vocab=51865 ------------
 # enc-dec, conv frontend STUB [arXiv:2212.04356]
 WHISPER_BASE = _register(ModelConfig(
@@ -244,7 +269,6 @@ def smoke_cfg(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config: tiny widths, few layers/experts, small
     vocab — used by per-arch CPU smoke tests."""
     kw = dict(
-        n_layers=len_scan_unit(cfg) * 2,
         d_model=64,
         vocab_size=128,
         norm_eps=1e-6,
@@ -264,9 +288,10 @@ def smoke_cfg(cfg: ModelConfig) -> ModelConfig:
     if cfg.d_ff:
         kw.update(d_ff=96)
     if cfg.moe is not None:
-        kw.update(moe=MoEConfig(
-            n_experts=4, top_k=2, d_ff=32, every=cfg.moe.every,
+        kw.update(moe=dataclasses.replace(
+            cfg.moe, n_experts=4, top_k=2, d_ff=32,
             capacity_factor=4.0,   # generous: smoke tests assume no drops
+            shared_d_ff=48 if cfg.moe.shared_d_ff else 0,
         ))
     if cfg.mamba is not None:
         kw.update(mamba=MambaConfig(
@@ -275,7 +300,11 @@ def smoke_cfg(cfg: ModelConfig) -> ModelConfig:
         ))
     if cfg.sliding_window is not None:
         kw.update(sliding_window=16)
-    return cfg.replace(**kw)
+    if cfg.layer_pattern is not None:
+        # each kind of layer once, in the order the pattern first has it
+        kw.update(layer_pattern="".join(dict.fromkeys(cfg.layer_pattern)))
+    cfg = cfg.replace(**kw)
+    return cfg.replace(n_layers=len_scan_unit(cfg) * 2)
 
 
 def len_scan_unit(cfg: ModelConfig) -> int:

@@ -37,6 +37,7 @@ from repro import telemetry
 from repro.core import fl, tdm
 from repro.core.relation import Relation
 from repro.launch import flops
+from repro.models import moe as moe_lib
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.optim import adamw
@@ -101,14 +102,19 @@ def _local_steps(loss_fn, opt_cfg: adamw.OptConfig, state, batch, n_steps: int):
         return state, jnp.stack(losses).mean()
 
 
-def _record_remat_saved_bytes(cfg: ModelConfig, state, batch, rec) -> None:
-    """Gauge ``fl.remat_saved_bytes``: the bytes one local step's forward
-    keeps for its backward by remat policy (a Mamba-2 layer's saved
-    in-projections and SSD output; 0 for a model without Mamba layers),
-    per node, from one node's abstract shapes. Costs one trace of the
+def _record_step_gauges(cfg: ModelConfig, state, batch, rec) -> None:
+    """Gauges of one local step, per node, from one node's abstract shapes:
+    ``fl.remat_saved_bytes``, the bytes the forward keeps for its backward
+    by remat policy (a Mamba-2 layer's saved in-projections and SSD output;
+    0 for a model without Mamba layers), and for a dropless MoE
+    ``moe.experts_held`` and ``moe.expert_rows``, the rows of one MoE
+    layer's static buffer for the held experts. Costs one trace of the
     loss, so it is recorded on a round-cache miss with tracing on only."""
     if not rec.tracing:
         return
+    tokens = int(np.prod(batch["tokens"].shape[2:]))
+    for name, value in moe_lib.step_gauges(tokens, cfg).items():
+        telemetry.set_gauge(name, value, rec=rec)
     loss_fn = registry.bundle(cfg).loss_fn
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), state["params"]
@@ -313,7 +319,7 @@ class RoundFnCache:
             )
             fn = build_fl_round(*self.args, rel, axis=self.axis)
             if example_args is not None:
-                _record_remat_saved_bytes(self.args[0], *example_args, rec)
+                _record_step_gauges(self.args[0], *example_args, rec)
             if rec.reconcile and example_args is not None:
                 with rec.span("fl.compile", cat="compile", links=len(rel) // 2):
                     fn = telemetry.compile_and_check(
@@ -367,8 +373,8 @@ def run_tdm_rounds(
     Telemetry: every round bumps default-on flight-recorder counters
     (``fl.rounds``, cache hit/miss, the oracle's per-round collective
     counts) — host-side dict updates only, no extra device syncs. With
-    tracing on, each compiled round also sets the gauge
-    ``fl.remat_saved_bytes`` from abstract shapes. Each
+    tracing on, each compiled round also sets the step's gauges
+    (``fl.remat_saved_bytes``, ``moe.*``) from abstract shapes. Each
     round runs inside an ``fl.round`` span, which a profiler session sees
     on the host plane and which the recorder keeps (``cat="slot"``) with
     tracing on. The span times the host's dispatch of the round, never the
@@ -841,7 +847,7 @@ def run_groundseg_fl(
             fn = build_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, up, down, pool
             )
-            _record_remat_saved_bytes(cfg, state, batch, rec)
+            _record_step_gauges(cfg, state, batch, rec)
             if rec.reconcile:
                 with rec.span("groundseg.compile", cat="compile", pool=pool):
                     fn = telemetry.compile_and_check(
@@ -967,7 +973,7 @@ def _run_groundseg_pipelined(
             fn = build_pipelined_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, wp, pool
             )
-            _record_remat_saved_bytes(cfg, state, batch, rec)
+            _record_step_gauges(cfg, state, batch, rec)
             if rec.reconcile:
                 with rec.span("groundseg.compile", cat="compile", pool=pool):
                     fn = telemetry.compile_and_check(

@@ -2,7 +2,8 @@
 
 One :class:`ModelConfig` describes any member of the LM family used here:
 dense transformer (gemma2/granite/qwen2/qwen2-vl), pure SSM (mamba2), hybrid
-(jamba), MoE (qwen3-moe/kimi-k2), and encoder–decoder (whisper). The config
+(jamba; nemotron-h, a pattern of single-mixer layers), MoE (qwen3-moe/kimi-k2),
+and encoder–decoder (whisper). The config
 is pure data — the model code in :mod:`repro.models.transformer` interprets
 it; the launch layer lowers it for a mesh.
 """
@@ -23,6 +24,20 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     aux_loss: float = 1e-2
+    # "capacity": a softmax router, per-row groups, tokens past an expert's
+    # capacity dropped. "dropless": a sigmoid router with a correction bias
+    # for the choice, ungated experts, and every assignment to a held expert
+    # computed, over a static buffer of tokens x min(top_k, held) rows (no
+    # auxiliary losses; set their weights to 0)
+    dispatch: str = "capacity"
+    routed_scale: float = 1.0  # dropless: the normalised top-k weights x this
+    shared_d_ff: int = 0      # one shared expert of this width on every token (0: none)
+    held: int = 0             # experts held here, of the n_experts routed over (0: all)
+    first_held: int = 0       # index of the first held expert
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -35,8 +50,11 @@ class MambaConfig:
     chunk: int = 256          # SSD chunk length (MXU-aligned)
     dt_min: float = 0.001
     dt_max: float = 0.1
+    heads: int = 0            # SSD heads; 0: expand * d_model / head_dim
 
     def d_inner(self, d_model: int) -> int:
+        if self.heads:
+            return self.heads * self.head_dim
         return self.expand * d_model
 
     def n_heads(self, d_model: int) -> int:
@@ -57,7 +75,7 @@ class ModelConfig:
 
     # attention flavor
     qkv_bias: bool = False
-    rope_theta: float = 10_000.0
+    rope_theta: Optional[float] = 10_000.0  # None: no positional encoding (NoPE)
     sliding_window: Optional[int] = None    # window size for local layers
     local_global_alternate: bool = False    # gemma2: even layers local
     force_local: bool = False               # every attn layer windowed (jamba
@@ -67,6 +85,8 @@ class ModelConfig:
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
 
     # mixer pattern (hybrid / ssm)
+    layer_pattern: Optional[str] = None     # one scan unit of single-mixer layers
+                                            # (nemotron-h): M mamba, E moe, * attn
     attn_every: Optional[int] = None        # jamba: 8 => layer i is attn iff i%8==0
     attn_free: bool = False                 # mamba2: no attention layers at all
     mamba: Optional[MambaConfig] = None
@@ -116,7 +136,15 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     # ------------------------------------------------------------- pattern
+    def pattern_at(self, i: int) -> Optional[str]:
+        """Layer ``i``'s kind in ``layer_pattern`` (None without one)."""
+        if self.layer_pattern is None:
+            return None
+        return self.layer_pattern[i % len(self.layer_pattern)]
+
     def layer_is_attn(self, i: int) -> bool:
+        if self.layer_pattern is not None:
+            return self.pattern_at(i) == "*"
         if self.attn_free:
             return False
         if self.attn_every is not None:
@@ -128,6 +156,8 @@ class ModelConfig:
         return bool(self.local_global_alternate and i % 2 == 0)
 
     def ffn_is_moe(self, i: int) -> bool:
+        if self.layer_pattern is not None:
+            return self.pattern_at(i) == "E"
         return self.moe is not None and (i % self.moe.every == self.moe.every - 1)
 
     # -------------------------------------------------------------- counts
@@ -159,7 +189,13 @@ class ModelConfig:
 
     def _moe_params(self) -> int:
         m = self.moe
-        return self.d_model * m.n_experts + m.n_experts * 3 * self.d_model * m.d_ff
+        if m.dispatch == "capacity":
+            return self.d_model * m.n_experts + m.n_experts * 3 * self.d_model * m.d_ff
+        n = (self.d_model + 1) * m.n_experts  # router and correction bias
+        n += m.n_held * 2 * self.d_model * m.d_ff  # ungated experts
+        if m.shared_d_ff:
+            n += self._mlp_params(m.shared_d_ff)
+        return n
 
     def _mamba_params(self) -> int:
         mb, D = self.mamba, self.d_model
@@ -177,6 +213,10 @@ class ModelConfig:
 
     def _block_params(self, i: int) -> int:
         D = self.d_model
+        kind = self.pattern_at(i)
+        if kind is not None:
+            mixer = {"M": self._mamba_params, "*": self._attn_params, "E": self._moe_params}
+            return mixer[kind]() + D
         n = 0
         if self.layer_is_attn(i):
             n += self._attn_params() + D  # + ln
@@ -202,7 +242,8 @@ class ModelConfig:
         m = self.moe
         n_moe_layers = sum(1 for i in range(self.n_layers) if self.ffn_is_moe(i))
         inactive_frac = (m.n_experts - m.top_k) / m.n_experts
-        inactive = int(n_moe_layers * m.n_experts * 3 * self.d_model * m.d_ff * inactive_frac)
+        expert = (3 if m.dispatch == "capacity" else 2) * self.d_model * m.d_ff
+        inactive = int(n_moe_layers * m.n_held * expert * inactive_frac)
         return total - inactive
 
 

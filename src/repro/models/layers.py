@@ -1,5 +1,5 @@
 """Base layers: params-as-pytrees with logical sharding axes, norms,
-embeddings, RoPE (+ M-RoPE), gated MLPs.
+embeddings, RoPE (+ M-RoPE), gated and plain MLPs.
 
 Convention: every ``init_*`` returns ``(params, specs)`` where ``specs``
 mirrors the params pytree and holds a tuple of *logical axis names* per
@@ -160,6 +160,8 @@ def activation(x: jax.Array, act: str) -> jax.Array:
         return jax.nn.silu(x)
     if act == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(act)
 
 

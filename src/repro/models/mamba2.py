@@ -9,7 +9,9 @@ memory-sane XLA path and the exact structure of the Pallas kernel
 ``kernels/ssd_scan/ref.py``.
 
 Layout: x (B,S,D) -> z,xc (B,S,di), B,C (B,S,G,N), dt (B,S,Hm);
-heads Hm = di / P share B/C within each of the G groups.
+heads Hm = di / P share B/C within each of the G groups, and the gated
+RMSNorm before the out-projection normalises each group's di / G channels
+on their own.
 """
 
 from __future__ import annotations
@@ -100,6 +102,20 @@ def init_mamba(key, cfg: ModelConfig) -> Tuple[Dict, Dict]:
         "out": ("mamba_inner", "embed"),
     }
     return p, s
+
+
+def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """RMSNorm of ``y * silu(z)`` over each of the G groups of channels
+    (norm after the gate, as Mamba-2's ``RMSNormGated`` with
+    ``group_size = d_inner / n_groups``); one group is a plain RMSNorm."""
+    G = cfg.mamba.n_groups
+    y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    if G == 1:
+        return rmsnorm(y, scale, cfg.norm_eps)
+    shape, dt = y.shape, y.dtype
+    g = y.astype(jnp.float32).reshape(shape[:-1] + (G, shape[-1] // G))
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + cfg.norm_eps)
+    return (g.reshape(shape) * (1.0 + scale.astype(jnp.float32))).astype(dt)
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +297,7 @@ def mamba_forward(p: Dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     y = checkpoint_name(y, SAVED)
     y = y + xh * p["D_skip"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B_, S, di)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm"], cfg.norm_eps)
+    y = gated_norm(y, z, p["norm"], cfg)
     return jnp.einsum("bsi,id->bsd", y, p["out"].astype(y.dtype))
 
 
@@ -306,7 +322,7 @@ def mamba_prefill(p: Dict, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, M
     y, final_state = ssd_chunked(xh, dt, A, Bv, Cv, min(mb.chunk, S))
     y = y + xh * p["D_skip"][None, None, :, None].astype(y.dtype)
     y = y.reshape(B_, S, di)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm"], cfg.norm_eps)
+    y = gated_norm(y, z, p["norm"], cfg)
     out = jnp.einsum("bsi,id->bsd", y, p["out"].astype(y.dtype))
     return out, MambaCache(ssm=final_state, conv=conv_tail)
 
@@ -357,6 +373,6 @@ def mamba_decode_step(
     y = jnp.einsum("bhn,bhpn->bhp", Ch.astype(jnp.float32), new_ssm)
     y = y + xh.astype(jnp.float32) * p["D_skip"][None, :, None]
     y = y.reshape(B_, 1, di).astype(x_t.dtype)
-    y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype), p["norm"], cfg.norm_eps)
+    y = gated_norm(y, z, p["norm"], cfg)
     out = jnp.einsum("bsi,id->bsd", y, p["out"].astype(y.dtype))
     return out, MambaCache(ssm=new_ssm, conv=new_conv)

@@ -11,7 +11,15 @@ over stacked params (O(1) HLO in depth):
 - ssm (mamba2):                    unit = [mamba],            L units
 - hybrid (jamba):                  unit = [attn+mlp, (mamba+moe, mamba+mlp)
                                            alternating x7],   L/8 units
+- layer pattern (nemotron-h):      unit = one single-mixer layer per
+                                   pattern letter (M mamba, E moe,
+                                   * attn), L/len(pattern) units
 - whisper decoder:                 unit = [attn+cross+mlp],   L units
+
+A layer is ``h + mixer(norm(h))`` and then, if it has an FFN,
+``h + ffn(norm2(h))``; a pattern's MoE layer has no token mixer. Each
+mixer runs under a named scope (``mamba``, ``attention``, ``moe``), so a
+profile of a training step attributes time to the kinds of layer.
 
 Caches: per attention layer a KV ring buffer (length = window for local
 layers — a sliding-window cache — else the max sequence length), per mamba
@@ -22,6 +30,7 @@ encoder KV. Decode scans units with the stacked cache as scan xs/ys.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -57,7 +66,7 @@ Params = Dict[str, Any]
 
 @dataclass(frozen=True)
 class LayerDesc:
-    mixer: str                  # "attn" | "mamba"
+    mixer: Optional[str]        # "attn" | "mamba" | None (an FFN-only layer)
     local: bool = False         # sliding-window attention
     ffn: Optional[str] = None   # "dense" | "moe" | None
     cross: bool = False         # cross-attention (whisper decoder)
@@ -65,6 +74,13 @@ class LayerDesc:
 
 def scan_unit(cfg: ModelConfig) -> List[LayerDesc]:
     """The per-unit layer pattern for this config (see module docstring)."""
+    if cfg.layer_pattern is not None:
+        kinds = {
+            "M": LayerDesc("mamba"),
+            "*": LayerDesc("attn", local=cfg.force_local),
+            "E": LayerDesc(None, ffn="moe"),
+        }
+        return [kinds[c] for c in cfg.layer_pattern]
     if cfg.family == "ssm":
         return [LayerDesc("mamba", ffn=None if cfg.no_ffn else "dense")]
     if cfg.family == "hybrid":
@@ -137,6 +153,8 @@ def _qkv(p: Params, x: jax.Array, cfg: ModelConfig):
 
 
 def _rope_qk(q, k, positions, cfg: ModelConfig):
+    if cfg.rope_theta is None:
+        return q, k
     if cfg.mrope_sections is not None:
         q = apply_mrope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions, cfg.rope_theta, cfg.mrope_sections)
@@ -204,7 +222,7 @@ def init_unit(key, cfg: ModelConfig) -> Tuple[Params, Dict]:
         lp["ln"], ls["ln"] = init_rmsnorm(cfg.d_model, dtype_of(cfg.param_dtype))
         if d.mixer == "attn":
             lp["attn"], ls["attn"] = init_attention(jax.random.fold_in(kj, 0), cfg)
-        else:
+        elif d.mixer == "mamba":
             lp["mamba"], ls["mamba"] = mamba_lib.init_mamba(
                 jax.random.fold_in(kj, 1), cfg
             )
@@ -214,7 +232,10 @@ def init_unit(key, cfg: ModelConfig) -> Tuple[Params, Dict]:
             )
             lp["cross"], ls["cross"] = init_attention(jax.random.fold_in(kj, 2), cfg)
         if d.ffn is not None:
-            lp["ln2"], ls["ln2"] = init_rmsnorm(cfg.d_model, dtype_of(cfg.param_dtype))
+            if d.mixer is not None:
+                lp["ln2"], ls["ln2"] = init_rmsnorm(
+                    cfg.d_model, dtype_of(cfg.param_dtype)
+                )
             if d.ffn == "moe":
                 lp["ffn"], ls["ffn"] = moe_lib.init_moe(jax.random.fold_in(kj, 3), cfg)
             else:
@@ -258,7 +279,7 @@ def init_unit_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
                     k=jnp.zeros((batch, cfg.enc_frames, KV, hd), cdt),
                     v=jnp.zeros((batch, cfg.enc_frames, KV, hd), cdt),
                 )
-        else:
+        elif d.mixer == "mamba":
             mb = cfg.mamba
             Hm = mb.n_heads(cfg.d_model)
             conv_dim = mb.d_inner(cfg.d_model) + 2 * mb.n_groups * mb.d_state
@@ -283,7 +304,7 @@ def cache_logical_specs(cfg: ModelConfig) -> Dict:
                     k=("layers", "batch", "frames", "kv_heads", None),
                     v=("layers", "batch", "frames", "kv_heads", None),
                 )
-        else:
+        elif d.mixer == "mamba":
             spec[f"mamba{j}"] = mamba_lib.MambaCache(
                 ssm=("layers", "batch", "mamba_heads", None, None),
                 conv=("layers", "batch", None, None),  # tiny: keep whole
@@ -378,45 +399,65 @@ def _unit_forward(
     enc_out: Optional[jax.Array],
     collect_cache: bool,
     max_len: int,
+    layer_remat: Callable = lambda fn: fn,
 ):
-    """Apply one unit. Returns (h, aux_losses, cache_entries)."""
+    """Apply one unit, each layer through ``layer_remat``. Returns (h,
+    aux_losses, cache_entries)."""
     descs = scan_unit(cfg)
     aux = {"moe_aux": jnp.zeros((), jnp.float32), "moe_zloss": jnp.zeros((), jnp.float32)}
     cache_out: Dict[str, Any] = {}
     for j, d in enumerate(descs):
-        p = unit_p[f"L{j}"]
+        layer = functools.partial(
+            _layer_forward, d=d, j=j, positions=positions, cfg=cfg, enc_out=enc_out,
+            collect_cache=collect_cache, max_len=max_len,
+        )
+        h, a, entries = layer_remat(layer)(h, unit_p[f"L{j}"])
+        if a is not None:
+            aux = {k_: aux[k_] + a[k_] for k_ in aux}
+        cache_out.update(entries)
+    return h, aux, cache_out
+
+
+def _layer_forward(h, p, *, d: LayerDesc, j: int, positions, cfg: ModelConfig,
+                   enc_out, collect_cache: bool, max_len: int):
+    """One layer. Returns (h, the MoE's aux losses or None, cache entries)."""
+    aux = None
+    cache_out: Dict[str, Any] = {}
+    if d.mixer is not None:
         hn = rmsnorm(h, p["ln"], cfg.norm_eps)
-        if d.mixer == "attn":
+    if d.mixer == "attn":
+        with jax.named_scope("attention"):
             q, k, v = _qkv(p["attn"], hn, cfg)
             q, k = _rope_qk(q, k, positions, cfg)
             out = flash_attention_train(q, k, v, _attn_spec(cfg, d))
             out = shard_activation(out, ("batch", "seq", "heads", None))
             h = h + jnp.einsum("bshk,hkd->bsd", out, p["attn"]["wo"].astype(out.dtype))
+        if collect_cache:
+            cache_out[f"kv{j}"] = _prefill_kv_cache(k, v, cfg, d, max_len)
+        if d.cross:
+            assert enc_out is not None
+            hc = rmsnorm(h, p["cross_ln"], cfg.norm_eps)
+            enc_kv = enc_kv_for_cross(p["cross"], enc_out, cfg)
+            h = h + cross_attn_train(p["cross"], hc, enc_kv, cfg)
             if collect_cache:
-                cache_out[f"kv{j}"] = _prefill_kv_cache(k, v, cfg, d, max_len)
-            if d.cross:
-                assert enc_out is not None
-                hc = rmsnorm(h, p["cross_ln"], cfg.norm_eps)
-                enc_kv = enc_kv_for_cross(p["cross"], enc_out, cfg)
-                h = h + cross_attn_train(p["cross"], hc, enc_kv, cfg)
-                if collect_cache:
-                    cache_out[f"cross{j}"] = KVCache(k=enc_kv[0], v=enc_kv[1])
-        else:
+                cache_out[f"cross{j}"] = KVCache(k=enc_kv[0], v=enc_kv[1])
+    elif d.mixer == "mamba":
+        with jax.named_scope("mamba"):
             if collect_cache:
                 out, mcache = mamba_lib.mamba_prefill(p["mamba"], hn, cfg)
                 cache_out[f"mamba{j}"] = mcache
             else:
                 out = mamba_lib.mamba_forward(p["mamba"], hn, cfg)
             h = h + out
-        if d.ffn is not None:
-            hn = rmsnorm(h, p["ln2"], cfg.norm_eps)
-            if d.ffn == "moe":
-                out, a = moe_lib.moe_apply(p["ffn"], hn, cfg)
-                aux = {k_: aux[k_] + a[k_] for k_ in aux}
-            else:
-                out = mlp_apply(p["ffn"], hn, cfg)
-            h = h + out
-        h = shard_activation(h, ("batch", "seq", None))
+    if d.ffn is not None:
+        hn = rmsnorm(h, p["ln2" if d.mixer is not None else "ln"], cfg.norm_eps)
+        if d.ffn == "moe":
+            with jax.named_scope("moe"):
+                out, aux = moe_lib.moe_apply(p["ffn"], hn, cfg)
+        else:
+            out = mlp_apply(p["ffn"], hn, cfg)
+        h = h + out
+    h = shard_activation(h, ("batch", "seq", None))
     return h, aux, cache_out
 
 
@@ -464,13 +505,20 @@ def forward_train(
         assert enc_embeds is not None
         enc_out = encoder_forward(params["encoder"], enc_embeds, cfg)
 
+    # a pattern's unit is a whole period of layers: each is its own
+    # checkpoint, so the backward holds one layer's recompute at a time
+    per_layer = cfg.layer_pattern is not None
+    layer_remat = functools.partial(remat_layer, cfg=cfg) if per_layer else (lambda fn: fn)
+
     def body(carry, unit_p):
         h, aux = carry
-        h, a, _ = _unit_forward(h, unit_p, positions, cfg, enc_out, False, S)
+        h, a, _ = _unit_forward(
+            h, unit_p, positions, cfg, enc_out, False, S, layer_remat=layer_remat
+        )
         aux = {k_: aux[k_] + a[k_] for k_ in aux}
         return (h, aux), None
 
-    fn = remat_layer(body, cfg)
+    fn = body if per_layer else remat_layer(body, cfg)
     aux0 = {
         "moe_aux": jnp.zeros((), jnp.float32),
         "moe_zloss": jnp.zeros((), jnp.float32),
@@ -584,7 +632,8 @@ def decode_step(
         new_c = dict(unit_c)
         for j, d in enumerate(descs):
             p = unit_p[f"L{j}"]
-            hn = rmsnorm(h, p["ln"], cfg.norm_eps)
+            if d.mixer is not None:
+                hn = rmsnorm(h, p["ln"], cfg.norm_eps)
             if d.mixer == "attn":
                 q, k, v = _qkv(p["attn"], hn, cfg)
                 q, k = _rope_qk(q, k, positions, cfg)
@@ -621,13 +670,13 @@ def decode_step(
                     h = h + jnp.einsum(
                         "bshk,hkd->bsd", out2, p["cross"]["wo"].astype(out2.dtype)
                     )
-            else:
+            elif d.mixer == "mamba":
                 mc: mamba_lib.MambaCache = unit_c[f"mamba{j}"]
                 out, new_mc = mamba_lib.mamba_decode_step(p["mamba"], hn, mc, cfg)
                 new_c[f"mamba{j}"] = new_mc
                 h = h + out
             if d.ffn is not None:
-                hn = rmsnorm(h, p["ln2"], cfg.norm_eps)
+                hn = rmsnorm(h, p["ln2" if d.mixer is not None else "ln"], cfg.norm_eps)
                 if d.ffn == "moe":
                     out, _ = moe_lib.moe_apply(p["ffn"], hn, cfg)
                 else:

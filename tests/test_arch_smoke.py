@@ -141,6 +141,7 @@ def test_param_counts_match_published_sizes():
         "qwen2-72b": (68e9, 76e9),
         "jamba-1.5-large-398b": (370e9, 420e9),
         "qwen3-moe-30b-a3b": (28e9, 33e9),
+        "nemotron-3-nano-30b-a3b": (31e9, 32e9),
         "kimi-k2-1t-a32b": (0.95e12, 1.1e12),
         "whisper-base": (0.05e9, 0.11e9),
         "qwen2-vl-72b": (68e9, 76e9),
@@ -157,7 +158,9 @@ def test_active_param_counts():
 
 def test_cell_enumeration():
     cells = list(archs.all_cells())
-    # 10 archs x 4 shapes - 8 long_500k skips (full-attention archs)
-    assert len(cells) == 32
+    # 11 archs x 4 shapes - 8 long_500k skips (full-attention archs)
+    assert len(cells) == 36
     longs = [c for c in cells if c[1] == "long_500k"]
-    assert sorted(x[0] for x in longs) == ["jamba-1.5-large-398b", "mamba2-780m"]
+    assert sorted(x[0] for x in longs) == [
+        "jamba-1.5-large-398b", "mamba2-780m", "nemotron-3-nano-30b-a3b"
+    ]

@@ -867,3 +867,51 @@ def test_telemetry_multidevice_suite():
     sys.stderr.write(proc.stderr)
     assert proc.returncode == 0, "worker failed"
     assert "ALL-OK" in proc.stdout
+
+
+def test_hybrid_round_carries_layer_scopes_and_moe_gauges():
+    """A round of the hybrid MoE (``nemotron-3-nano-30b-a3b`` at a smoke
+    size) carries its kinds of layer as named scopes inside ``local_step``
+    (``mamba``, ``attention``, ``moe``, and inside ``moe``: ``router``,
+    ``experts``, ``shared_expert``), none of them a scope the benchmark's
+    readers attribute time to; with tracing on, compiling it sets the MoE
+    gauges (experts held, one MoE layer's rows a step) and the remat gauge."""
+    import jax
+    import jax.numpy as jnp
+
+    from bench import scopes
+    from repro.configs import archs
+    from repro.launch import fl_train
+    from repro.launch import mesh as mesh_lib
+    from repro.models import registry
+    from repro.optim import adamw
+
+    cfg = archs.smoke_cfg(archs.get("nemotron-3-nano-30b-a3b"))
+    opt_cfg = adamw.OptConfig()
+    mesh = mesh_lib.make_mesh((1,), ("data",))
+    cache = fl_train.RoundFnCache(cfg, opt_cfg, mesh, 1, fl_train.FLConfig())
+    params = jax.eval_shape(
+        lambda k: registry.bundle(cfg).init(k)[0], jax.random.PRNGKey(0)
+    )
+    state = {
+        "params": params,
+        "opt": jax.eval_shape(lambda p: adamw.init_opt_state(p, opt_cfg), params),
+        "step": jax.ShapeDtypeStruct((), jnp.int32),
+    }
+    state = jax.tree.map(lambda x: jax.ShapeDtypeStruct((1,) + x.shape, x.dtype), state)
+    B, S = 2, 16
+    batch = {k: jax.ShapeDtypeStruct((1, 1, B, S), jnp.int32) for k in ("tokens", "labels")}
+    with telemetry.record_scope(tracing=True) as rec:
+        fn = cache(Relation.from_edges([], nodes=range(1)), example_args=(state, batch))
+        gauges = telemetry.metrics_snapshot(rec)["gauges"]
+    hlo = fn.lower(state, batch).compile().as_text()
+    # op_names without their transformations: jvp(moe)/router -> moe/router
+    stacks = {re.sub(r"[\w-]+\(|\)", "", n) for n in re.findall(r'op_name="([^"]*)"', hlo)}
+    for scope in ("mamba", "attention", "moe/router", "moe/experts", "moe/shared_expert"):
+        assert any(re.search(f"local_step/(.+/)?{scope}/", s) for s in stacks), scope
+    assert not {"mamba", "attention", "moe", "router", "experts", "shared_expert"} & set(
+        scopes.SCOPES
+    )
+    assert gauges["moe.experts_held"] == cfg.moe.n_held == 4
+    assert gauges["moe.expert_rows"] == B * S * cfg.moe.top_k
+    assert gauges["fl.remat_saved_bytes"] > 0

@@ -114,3 +114,32 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     (call,) = [l for l in text.splitlines() if "tpu_custom_call" in l]
     assert call.split("=")[0].split()[-1].lstrip("%").split(".")[0] == kernel
     assert f"/{kernel}/pallas_call" in call
+
+
+def test_moe_grouped_matmuls_compile_for_v5e(one_chip, no_compile_cache, monkeypatch):
+    """The held experts of one ``nemotron-3-nano-30b-a3b`` MoE layer at its
+    published widths (D 2688, expert width 1856, top-6 of 128, 8 held) over
+    the benchmark's 16,384 tokens, forward and backward, take the TPU path
+    of ``moe.grouped_matmul``: megablox ``gmm`` and ``tgmm`` kernels, which
+    a trace names after them (``moe.expert_kernel_ms`` reads them)."""
+    import dataclasses
+
+    from repro.configs import archs
+    from repro.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the described chip
+    cfg = archs.get("nemotron-3-nano-30b-a3b")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held=8))
+    params = jax.eval_shape(lambda k: moe.init_dropless(k, cfg)[0], jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _arg(x.shape, x.dtype, one_chip), params)
+    x = _arg((2, 8192, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        return jnp.sum(moe.moe_dropless(p, x, cfg)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    names = [l.split("=")[0].split()[-1].lstrip("%").split(".")[0]
+             for l in text.splitlines() if "tpu_custom_call" in l]
+    # two matmuls forward; in the backward each takes an input gradient
+    # (gmm) and a weight gradient (tgmm)
+    assert sorted(names) == ["gmm"] * 4 + ["tgmm"] * 2
