@@ -107,8 +107,9 @@ def _record_step_gauges(cfg: ModelConfig, state, batch, rec) -> None:
     ``fl.remat_saved_bytes``, the bytes the forward keeps for its backward
     by remat policy (a Mamba-2 layer's saved in-projections and SSD output;
     0 for a model without Mamba layers), and for a dropless MoE
-    ``moe.experts_held`` and ``moe.expert_rows``, the rows of one MoE
-    layer's static buffer for the held experts. Costs one trace of the
+    ``moe.experts_held``, ``moe.expert_rows``, the bound of one MoE layer's
+    buffer for the held experts, and ``moe.expert_rows_first``, the first
+    size of its ladder (``moe.size_ladder``). Costs one trace of the
     loss, so it is recorded on a round-cache miss with tracing on only."""
     if not rec.tracing:
         return

@@ -27,8 +27,9 @@ class MoEConfig:
     # "capacity": a softmax router, per-row groups, tokens past an expert's
     # capacity dropped. "dropless": a sigmoid router with a correction bias
     # for the choice, ungated experts, and every assignment to a held expert
-    # computed, over a static buffer of tokens x min(top_k, held) rows (no
-    # auxiliary losses; set their weights to 0)
+    # computed, over the smallest of a ladder of static buffers that holds
+    # them, up to tokens x min(top_k, held) rows (no auxiliary losses; set
+    # their weights to 0)
     dispatch: str = "capacity"
     routed_scale: float = 1.0  # dropless: the normalised top-k weights x this
     shared_d_ff: int = 0      # one shared expert of this width on every token (0: none)

@@ -17,19 +17,24 @@ resharded expert->model, which lowers to the canonical MoE all-to-all.
 ``n_experts`` its router spans (the chip's share of an expert-parallel
 layer) and computes, for every token, only its held experts' part of the
 result, with nothing dropped: the assignments to held experts are sorted by
-expert into a static buffer of tokens x min(top_k, held) rows, the most
-that can arrive, and the experts run as grouped matmuls whose groups are
-the held experts' row counts (:func:`grouped_matmul`). A shared expert,
-which every chip computes alike, is added to every token.
+expert into a static buffer, and the experts run as grouped matmuls whose
+groups are the held experts' row counts (:func:`grouped_matmul`). The
+buffer is the smallest of a short ladder of static sizes
+(:func:`size_ladder`) that holds the step's held assignments, chosen on the
+device; the last, tokens x min(top_k, held) rows, holds the most that can
+arrive. A shared expert, which every chip computes alike, is added to every
+token.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.models.layers import activation, dtype_of, init_mlp, mlp_apply, truncated_normal
@@ -103,22 +108,41 @@ def buffer_rows(tokens: int, cfg: ModelConfig) -> int:
     return tokens * min(m.top_k, m.n_held)
 
 
+GMM_TM = 512  # rows of a grouped-matmul tile
+
+
+def size_ladder(tokens: int, cfg: ModelConfig) -> Tuple[int, ...]:
+    """Static sizes of the held experts' buffer for ``tokens`` tokens, from
+    shapes alone: the first is twice the held experts' even share of the
+    assignments (tokens x top_k x held / n_experts) rounded up to a
+    grouped-matmul tile, each next one doubles the last, and the last is
+    :func:`buffer_rows`, the most that can arrive. A single size where the
+    first already reaches that bound."""
+    m = cfg.moe
+    bound = buffer_rows(tokens, cfg)
+    size = -(-2 * tokens * m.top_k * m.n_held // (m.n_experts * GMM_TM)) * GMM_TM
+    sizes = []
+    while size < bound:
+        sizes.append(size)
+        size *= 2
+    return (*sizes, bound)
+
+
 def step_gauges(tokens: int, cfg: ModelConfig) -> Dict[str, int]:
-    """Gauges of a training step of ``tokens`` tokens: ``moe.experts_held``
-    and ``moe.expert_rows`` (one MoE layer's static buffer) of a dropless
+    """Gauges of a training step of ``tokens`` tokens: ``moe.experts_held``,
+    ``moe.expert_rows`` (the bound of one MoE layer's buffer) and
+    ``moe.expert_rows_first`` (the first size of its ladder) of a dropless
     MoE; none for a model without one."""
     if cfg.moe is None or cfg.moe.dispatch != "dropless":
         return {}
-    return {"moe.experts_held": cfg.moe.n_held, "moe.expert_rows": buffer_rows(tokens, cfg)}
+    return {"moe.experts_held": cfg.moe.n_held, "moe.expert_rows": buffer_rows(tokens, cfg),
+            "moe.expert_rows_first": size_ladder(tokens, cfg)[0]}
 
 
 def _tile(d: int) -> int:
     """The largest multiple of 128 up to 1024 that divides ``d`` (else 128:
     the kernel masks a ragged last tile)."""
     return max((t for t in range(128, 1025, 128) if d % t == 0), default=128)
-
-
-GMM_TM = 512  # rows of a grouped-matmul tile
 
 
 def grouped_matmul(a: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
@@ -140,6 +164,62 @@ def grouped_matmul(a: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
     return out[:R]
 
 
+def _held_experts(n: int, cfg: ModelConfig, xt, w, wi, wo, key, order, sizes):
+    """The held experts' part of the output, f32 (T, D), over a buffer of the
+    first ``n`` assignments in ``order`` (sorted by ``key``: held expert,
+    then H for one not held here), which holds every held one."""
+    H, K = cfg.moe.n_held, cfg.moe.top_k
+    with jax.named_scope(f"rows_{n}"):
+        order = order[:n]
+        held = (key[order] < H)[:, None]
+        rows = order // K
+
+        def grouped(a, wt):
+            # the rows past the held assignments are in no group, and the
+            # kernel leaves them unwritten, forward and backward: they are
+            # masked on the way in (for the gradient) and on the way out
+            a = jnp.where(held, a, 0)
+            return jnp.where(held, grouped_matmul(a, wt.astype(xt.dtype), sizes), 0)
+
+        h = activation(grouped(xt[rows], wi), cfg.act)
+        y = grouped(h, wo).astype(jnp.float32) * w[order][:, None]
+        return jnp.zeros(xt.shape, jnp.float32).at[rows].add(y)
+
+
+def _held_experts_on_ladder(ladder: Tuple[int, ...], cfg: ModelConfig):
+    """:func:`_held_experts` over the smallest size of ``ladder`` that holds
+    the held assignments, chosen on the device. Its backward switches on the
+    same size and stores only the inputs: a ``lax.switch`` under ``grad``
+    would keep the residuals of every branch, the full buffer's among them.
+    A ladder of one size runs it with no switch."""
+    branches = [functools.partial(_held_experts, n, cfg) for n in ladder]
+    if len(branches) == 1:
+        return branches[0]
+    bounds = np.asarray(ladder[:-1], np.int32)  # a constant, in whichever trace
+
+    def choose(sizes):
+        return jnp.sum(jnp.sum(sizes) > bounds)
+
+    @jax.custom_vjp
+    def run(xt, w, wi, wo, key, order, sizes):
+        return jax.lax.switch(choose(sizes), branches, xt, w, wi, wo, key, order, sizes)
+
+    def fwd(*args):
+        return run(*args), args
+
+    def bwd(args, g):
+        def branch(f):
+            def vjp(xt, w, wi, wo, key, order, sizes, g):
+                return jax.vjp(lambda *a: f(*a, key, order, sizes), xt, w, wi, wo)[1](g)
+            return vjp
+
+        grads = jax.lax.switch(choose(args[-1]), [branch(f) for f in branches], *args, g)
+        return (*grads, None, None, None)
+
+    run.defvjp(fwd, bwd)
+    return run
+
+
 def moe_dropless(
     p: Dict, x: jax.Array, cfg: ModelConfig
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -147,7 +227,7 @@ def moe_dropless(
     the shared expert's, no auxiliary losses)."""
     m = cfg.moe
     shape, cdt = x.shape, x.dtype
-    D, H, K = shape[-1], m.n_held, m.top_k
+    D, H = shape[-1], m.n_held
     xt = x.reshape(-1, D)
     T = xt.shape[0]
     with jax.named_scope("router"):
@@ -155,27 +235,15 @@ def moe_dropless(
     with jax.named_scope("experts"):
         local = top_e.reshape(-1) - m.first_held
         key = jnp.where((local >= 0) & (local < H), local, H)   # H: not held here
-        order = jnp.argsort(key, stable=True)[: buffer_rows(T, cfg)]
+        order = jnp.argsort(key, stable=True)
         sizes = jnp.sum(jax.nn.one_hot(key, H, dtype=jnp.int32), axis=0)
-        held = (key[order] < H)[:, None]
-        rows = order // K
-        w = top_w.reshape(-1)[order][:, None]
-
-        def grouped(a, wt):
-            # the rows past the held assignments are in no group, and the
-            # kernel leaves them unwritten, forward and backward: they are
-            # masked on the way in (for the gradient) and on the way out
-            a = jnp.where(held, a, 0)
-            return jnp.where(held, grouped_matmul(a, wt.astype(cdt), sizes), 0)
-
         # the expert width padded to a multiple of 128 for the kernel's
         # tiles (the published 1856 is not one): the padded units are 0
         pad = -p["wi"].shape[-1] % 128
         wi = jnp.pad(p["wi"], ((0, 0), (0, 0), (0, pad)))
         wo = jnp.pad(p["wo"], ((0, 0), (0, pad), (0, 0)))
-        h = activation(grouped(xt[rows], wi), cfg.act)
-        y = grouped(h, wo).astype(jnp.float32) * w
-        out = jnp.zeros((T, D), jnp.float32).at[rows].add(y)
+        experts = _held_experts_on_ladder(size_ladder(T, cfg), cfg)
+        out = experts(xt, top_w.reshape(-1), wi, wo, key, order, sizes)
     if m.shared_d_ff:
         with jax.named_scope("shared_expert"):
             out = out + mlp_apply(p["shared"], xt, cfg).astype(jnp.float32)

@@ -9,6 +9,7 @@ the round programs of the benchmark's other configurations, unchanged by it.
 import copy
 import dataclasses
 import hashlib
+import math
 import pathlib
 import re
 import sys
@@ -61,9 +62,9 @@ def program_cfg(conf, model):
     )
 
 
-def _batch(vocab, seed=0):
+def _batch(vocab, seed=0, seq=S):
     rng = np.random.default_rng(seed)
-    stream = rng.integers(0, vocab, (B, S + 1))
+    stream = rng.integers(0, vocab, (B, seq + 1))
     return {"tokens": jnp.asarray(stream[:, :-1], jnp.int32),
             "labels": jnp.asarray(stream[:, 1:], jnp.int32)}
 
@@ -126,6 +127,23 @@ def test_program_matches_reference(tiny):
     # only ranks the choice and has no gradient
     n_moe = conf["hybrid_override_pattern"].count("E")
     assert len(grad_gaps) == len(jax.tree.leaves(params)) - n_moe
+
+
+def test_program_on_a_ladder_matches_reference(tiny):
+    """At 2 x 128 tokens each MoE layer's buffer has two sizes (512 and
+    1,024 rows), so the layers run the ladder's switch inside the program's
+    layer scan and checkpoints, forward and backward: the program still
+    matches the reference."""
+    conf, model, params, _, _ = tiny
+    cfg = program_cfg(conf, model)
+    batch = _batch(conf["vocab_size"], seq=128)
+    assert len(moe.size_ladder(B * 128, cfg)) == 2
+    prog = jax.jit(jax.value_and_grad(
+        lambda p: registry.bundle(cfg).loss_fn(p, batch)[0]))(params)
+    loss_gap, grad_gaps = _gaps(prog, _reference(model, conf, params, batch))
+    assert loss_gap <= LOSS_RTOL, loss_gap
+    worst = max(grad_gaps, key=grad_gaps.get)
+    assert grad_gaps[worst] <= GRAD_RTOL, (worst, grad_gaps[worst])
 
 
 def test_bf16_reference_fails_the_tolerances(tiny):
@@ -222,18 +240,99 @@ def _undefined_past_groups(a, w, sizes):
     return f(a, w, sizes)
 
 
-def test_rows_past_the_groups_stay_out(monkeypatch):
-    """The buffer's rows past the held assignments belong to no group; what
-    the kernel leaves in them reaches neither the output nor a gradient."""
-    conf, model, cfg, p, x = _moe_setup(seed=2)
+# Where one MoE layer's held assignments land in the ladder of buffer sizes
+# (512, 1024, 2048, 4096 rows for 1024 tokens, top-4 of 64 experts, 4 held):
+# the correction bias raises some held experts above every other, so each
+# token sends them its assignments. "single": a layer whose ladder is one
+# size (24 tokens of 16 experts: 512 rows reach the bound of 96).
+LADDER_CASES = {
+    "single": (dict(seed=2), 0, 0),
+    "first": (dict(n_experts=64, seed=2, T=1024), 0, 0),
+    "middle": (dict(n_experts=64, seed=2, T=1024), 1, 2),
+    "full": (dict(n_experts=64, seed=2, T=1024), 4, 3),
+}
+
+
+def _ladder_case(case):
+    """(conf, model, cfg, the share's params, x) of a case, the layer
+    holding experts 4-7, its held assignments checked to take the case's
+    size of the ladder."""
+    setup, raised, index = LADDER_CASES[case]
+    conf, model, cfg, p, x = _moe_setup(**setup)
+    E, T = p["router"].shape[1], x.shape[1]
+    if raised:
+        p = dict(p, router_bias=jnp.zeros((E,)).at[4:4 + raised].set(10.0))
     cfg, q = _share(cfg, p, 4, 4)
-    loss = lambda q, x: jnp.sum(jnp.sin(moe.moe_dropless(q, x, cfg)[0]))
+    top_e, _ = moe.route(q, x.reshape(T, -1), cfg)
+    count = int(jnp.sum((top_e >= 4) & (top_e < 8)))
+    ladder = moe.size_ladder(T, cfg)
+    assert sum(count > n for n in ladder[:-1]) == index, (count, ladder)
+    return conf, model, cfg, q, x
+
+
+def _sin_loss(cfg):
+    return lambda q, x: jnp.sum(jnp.sin(moe.moe_dropless(q, x, cfg)[0]))
+
+
+@pytest.mark.parametrize("case", ["first", "middle", "full"])
+def test_ladder_matches_the_full_buffer(case, monkeypatch):
+    """Whichever size of the ladder holds the held assignments, the output
+    and the gradients (params and x) are those of the whole buffer of
+    tokens x min(top_k, held) rows, and the uncut reference's."""
+    conf, model, cfg, q, x = _ladder_case(case)
+    loss = _sin_loss(cfg)
+    out = moe.moe_dropless(q, x, cfg)[0]
+    grads = jax.grad(loss, argnums=(0, 1))(q, x)
+    monkeypatch.setattr(moe, "size_ladder", lambda T, cfg: (moe.buffer_rows(T, cfg),))
+    np.testing.assert_allclose(out, moe.moe_dropless(q, x, cfg)[0], rtol=1e-6, atol=1e-6)
+    whole = jax.grad(loss, argnums=(0, 1))(q, x)
+    d = dict(model.dims(conf), held=4, first=4)
+    with jax.default_matmul_precision("highest"):
+        ref_out = model.moe(q, x, d, lambda a: a)
+        ref = jax.grad(lambda q, x: jnp.sum(jnp.sin(model.moe(q, x, d, lambda a: a))),
+                       argnums=(0, 1))(q, x)
+    np.testing.assert_allclose(out, ref_out, rtol=1e-5, atol=1e-5)
+    for (path, a), b, c in zip(jax.tree_util.tree_leaves_with_path(grads),
+                               jax.tree.leaves(whole), jax.tree.leaves(ref)):
+        assert _rel_gap(a, b) <= 1e-6, (jax.tree_util.keystr(path), _rel_gap(a, b))
+        assert _rel_gap(a, c) <= GRAD_RTOL, (jax.tree_util.keystr(path), _rel_gap(a, c))
+
+
+@pytest.mark.parametrize("case", sorted(LADDER_CASES))
+def test_rows_past_the_groups_stay_out(case, monkeypatch):
+    """The buffer's rows past the held assignments belong to no group; what
+    the kernel leaves in them reaches neither the output nor a gradient,
+    in whichever size of the ladder the layer runs."""
+    conf, model, cfg, q, x = _ladder_case(case)
+    loss = _sin_loss(cfg)
     clean = jax.value_and_grad(loss, argnums=(0, 1))(q, x)
     monkeypatch.setattr(moe, "grouped_matmul", _undefined_past_groups)
     dirty = jax.value_and_grad(loss, argnums=(0, 1))(q, x)
     for a, b in zip(jax.tree.leaves(dirty), jax.tree.leaves(clean)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("tokens,held,want", [
+    (16384, 8, (12288, 24576, 49152, 98304)),  # solo8k: 2 x 8192 tokens, 8 of 128 held
+    (1000, 8, (1024, 2048, 4096, 6000)),       # the last size is the bound, off the tiles
+    (16384, 128, (98304,)),                    # every expert held: the first is the bound
+    (32, 8, (192,)),                           # a few tokens: one tile is past the bound
+])
+def test_size_ladder(tokens, held, want):
+    """The buffer's sizes come from shapes alone: they increase, each but a
+    bound is a multiple of the grouped-matmul tile, the first is twice the
+    held experts' even share rounded up to it, and the last is the bound."""
+    conf, model = harness.load_config(NAME)
+    cfg = model.program_config(conf, archs)
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held=held))
+    ladder = moe.size_ladder(tokens, cfg)
+    assert ladder == want
+    assert list(ladder) == sorted(set(ladder))
+    assert ladder[-1] == moe.buffer_rows(tokens, cfg)
+    assert all(n % moe.GMM_TM == 0 for n in ladder[:-1])
+    even = tokens * cfg.moe.top_k * held / cfg.moe.n_experts
+    assert ladder[0] == min(moe.GMM_TM * math.ceil(2 * even / moe.GMM_TM), ladder[-1])
 
 
 @pytest.mark.parametrize("groups", [1, 2, 8])

@@ -875,7 +875,8 @@ def test_hybrid_round_carries_layer_scopes_and_moe_gauges():
     (``mamba``, ``attention``, ``moe``, and inside ``moe``: ``router``,
     ``experts``, ``shared_expert``), none of them a scope the benchmark's
     readers attribute time to; with tracing on, compiling it sets the MoE
-    gauges (experts held, one MoE layer's rows a step) and the remat gauge."""
+    gauges (experts held, one MoE layer's buffer bound and the first size of
+    its ladder) and the remat gauge."""
     import jax
     import jax.numpy as jnp
 
@@ -914,4 +915,26 @@ def test_hybrid_round_carries_layer_scopes_and_moe_gauges():
     )
     assert gauges["moe.experts_held"] == cfg.moe.n_held == 4
     assert gauges["moe.expert_rows"] == B * S * cfg.moe.top_k
+    # 32 tokens: the ladder's first size, one grouped-matmul tile, is past
+    # the bound, so the buffer has that one size
+    assert gauges["moe.expert_rows_first"] == gauges["moe.expert_rows"]
     assert gauges["fl.remat_saved_bytes"] > 0
+
+
+def test_moe_step_gauges_of_the_benchmark_cells():
+    """The MoE gauges of a step at the benchmark's shapes: solo8k's
+    2 x 8192 tokens route into a buffer of at most 98,304 rows (6 of each
+    token's assignments, 8 of the 128 experts held), whose first size is
+    twice the held experts' even share, 12,288; a model without a MoE has
+    none."""
+    from bench import harness
+    from repro.configs import archs
+    from repro.models import moe
+
+    conf, model = harness.load_config("nemotron-3-nano-30b-a3b")
+    cfg = model.program_config(conf, archs)
+    assert moe.step_gauges(2 * 8192, cfg) == {
+        "moe.experts_held": 8, "moe.expert_rows": 98_304, "moe.expert_rows_first": 12_288,
+    }
+    conf, model = harness.load_config("mamba2-780m")
+    assert moe.step_gauges(2 * 512, model.program_config(conf, archs)) == {}

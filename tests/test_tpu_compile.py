@@ -119,13 +119,17 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
 def test_moe_grouped_matmuls_compile_for_v5e(one_chip, no_compile_cache, monkeypatch):
     """The held experts of one ``nemotron-3-nano-30b-a3b`` MoE layer at its
     published widths (D 2688, expert width 1856, top-6 of 128, 8 held) over
-    the benchmark's 16,384 tokens, forward and backward, take the TPU path
-    of ``moe.grouped_matmul``: megablox ``gmm`` and ``tgmm`` kernels, which
-    a trace names after them (``moe.expert_kernel_ms`` reads them)."""
+    the benchmark's 16,384 tokens, forward and backward under the round's
+    remat policy, take the TPU path of ``moe.grouped_matmul``: megablox
+    ``gmm`` and ``tgmm`` kernels, which a trace names after them
+    (``moe.expert_kernel_ms`` reads them), in one branch per size of the
+    buffer's ladder, each under its ``rows_<n>`` scope."""
+    import collections
     import dataclasses
+    import re
 
     from repro.configs import archs
-    from repro.models import moe
+    from repro.models import moe, transformer
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the described chip
     cfg = archs.get("nemotron-3-nano-30b-a3b")
@@ -134,12 +138,31 @@ def test_moe_grouped_matmuls_compile_for_v5e(one_chip, no_compile_cache, monkeyp
     params = jax.tree.map(lambda x: _arg(x.shape, x.dtype, one_chip), params)
     x = _arg((2, 8192, cfg.d_model), jnp.bfloat16, one_chip)
 
+    def layer(p, x):
+        return x + moe.moe_dropless(p, x, cfg)[0]
+
     def loss(p, x):
-        return jnp.sum(moe.moe_dropless(p, x, cfg)[0].astype(jnp.float32))
+        # the gradient needs the layer's output, as the next layer's does
+        y = transformer.remat_layer(layer, cfg)(p, x)
+        return jnp.sum(jnp.sin(y.astype(jnp.float32)))
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
-    names = [l.split("=")[0].split()[-1].lstrip("%").split(".")[0]
-             for l in text.splitlines() if "tpu_custom_call" in l]
-    # two matmuls forward; in the backward each takes an input gradient
-    # (gmm) and a weight gradient (tgmm)
-    assert sorted(names) == ["gmm"] * 4 + ["tgmm"] * 2
+    kernels = collections.defaultdict(list)
+    for line in text.splitlines():
+        if "tpu_custom_call" in line:
+            (rows,) = set(re.findall(r"\brows_(\d+)\b", line))
+            kernels[int(rows)].append(line.split("=")[0].split()[-1].lstrip("%").split(".")[0])
+    ladder = moe.size_ladder(2 * 8192, cfg)
+    assert ladder == (12288, 24576, 49152, 98304)
+    # in each size's branch: two matmuls forward, and in the backward the
+    # same two again (the layer is rematerialised) with an input gradient
+    # (gmm) and a weight gradient (tgmm) each, as the whole buffer took
+    # without the ladder
+    assert {n: sorted(names) for n, names in kernels.items()} == {
+        n: ["gmm"] * 6 + ["tgmm"] * 2 for n in ladder
+    }
+    # one switch forward, returning the layer's output alone, and one
+    # backward: no branch writes the full buffer's rows for the backward
+    switches = [l.split("conditional(")[0] for l in text.splitlines() if " conditional(" in l]
+    assert len(switches) == 2
+    assert not any(f"[{ladder[-1]}," in out for out in switches), switches
