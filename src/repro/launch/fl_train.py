@@ -12,6 +12,11 @@ so the per-slot relation literally becomes the collective schedule
                       visibility, ring, hypercube, ...), optionally int8 /
                       top-k (CHOCO) compressed
 
+Every round, whatever its exchange (gossip, hierarchical gossip, ground
+sinks, pipelined windows), is one program built by ``_round_program``, and
+every round loop keeps its programs in one compile-on-miss cache
+(``_ProgramCache``).
+
 Time-varying schedules: :class:`RoundFnCache` + :func:`run_tdm_rounds` drive
 one FL round per slot relation, recompiling only on unseen topologies;
 :func:`run_constellation_fl` feeds them straight from a geometry-derived
@@ -37,7 +42,6 @@ from repro import telemetry
 from repro.core import fl, tdm
 from repro.core.relation import Relation
 from repro.launch import flops
-from repro.models import moe as moe_lib
 from repro.models import registry
 from repro.models.config import ModelConfig
 from repro.optim import adamw
@@ -104,27 +108,93 @@ def _local_steps(loss_fn, opt_cfg: adamw.OptConfig, state, batch, n_steps: int):
 
 def _record_step_gauges(cfg: ModelConfig, state, batch, rec) -> None:
     """Gauges of one local step, per node, from one node's abstract shapes:
-    ``fl.remat_saved_bytes``, the bytes the forward keeps for its backward
-    by remat policy (a Mamba-2 layer's saved in-projections and SSD output;
-    0 for a model without Mamba layers), and for a dropless MoE
-    ``moe.experts_held``, ``moe.expert_rows``, the bound of one MoE layer's
-    buffer for the held experts, and ``moe.expert_rows_first``, the first
-    size of its ladder (``moe.size_ladder``). Costs one trace of the
-    loss, so it is recorded on a round-cache miss with tracing on only."""
+    the model's own (:meth:`repro.models.registry.ModelBundle.step_gauges`:
+    a dropless MoE's ``moe.experts_held``, ``moe.expert_rows`` and
+    ``moe.expert_rows_first``), then ``fl.remat_saved_bytes``, the bytes
+    the forward keeps for its backward by remat policy (a Mamba-2 layer's
+    saved in-projections and SSD output; 0 for a model without Mamba
+    layers). Costs one trace of the loss, so it is recorded on a
+    round-cache miss with tracing on only."""
     if not rec.tracing:
         return
+    bundle = registry.bundle(cfg)
     tokens = int(np.prod(batch["tokens"].shape[2:]))
-    for name, value in moe_lib.step_gauges(tokens, cfg).items():
+    for name, value in bundle.step_gauges(tokens).items():
         telemetry.set_gauge(name, value, rec=rec)
-    loss_fn = registry.bundle(cfg).loss_fn
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), state["params"]
     )
     step_batch = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape[2:], x.dtype), batch
     )
-    saved = flops.remat_saved_bytes(lambda p, b: loss_fn(p, b)[0], params, step_batch)
+    saved = flops.remat_saved_bytes(
+        lambda p, b: bundle.loss_fn(p, b)[0], params, step_batch
+    )
     telemetry.set_gauge("fl.remat_saved_bytes", saved, rec=rec)
+
+
+def _n_buckets(stacked_params) -> int:
+    """Dtype buckets of the fused spec, read from the leaves' dtypes without
+    touching device values (no slicing — counters must stay sync-free)."""
+    return len({leaf.dtype.name for leaf in jax.tree.leaves(stacked_params)})
+
+
+def _round_program(
+    cfg: ModelConfig,
+    opt_cfg: adamw.OptConfig,
+    mesh: Mesh,
+    axis,
+    local_steps: int,
+    exchange: Callable,
+    *,
+    sink_mask: Optional[np.ndarray] = None,
+    aux: bool = False,
+) -> Callable:
+    """The program of every FL round: each node runs ``local_steps`` AdamW
+    steps on its own batches, then ``exchange`` under the ``exchange`` name
+    scope. Returns a jit'd ``(stacked_state[, aux], stacked_batch) ->
+    (stacked_state[, aux], losses)`` whose arguments carry a leading node
+    axis sharded over ``axis`` (a mesh axis name, or a tuple of them) and
+    whose state (and ``aux``) is donated.
+
+    ``exchange(params[, aux]) -> (params[, aux])`` runs on one node's
+    parameters, and on its ``aux`` buffers when ``aux`` is set. Where
+    ``sink_mask[node]`` is set, the node keeps its state from before the
+    local steps: ground sinks are aggregation infrastructure, not learners
+    (their lanes compute and discard, as SPMD demands)."""
+    loss_fn = registry.bundle(cfg).loss_fn
+
+    def node_round(*args):
+        # every argument's leading dim = 1 (this node's shard); squeeze it
+        state, *aux_in, batch = [jax.tree.map(lambda x: x[0], a) for a in args]
+        if sink_mask is not None:
+            idx = jax.lax.axis_index(axis)
+            is_sink = jnp.asarray(sink_mask)[idx]
+
+        trained, local_loss = _local_steps(
+            loss_fn, opt_cfg, state, batch, local_steps
+        )
+        if sink_mask is not None:
+            trained = jax.tree.map(
+                lambda new, old: jnp.where(is_sink, old, new), trained, state
+            )
+
+        with jax.named_scope("exchange"):
+            params, *aux_out = exchange(trained["params"], *aux_in)
+        out = [dict(trained, params=params), *aux_out]
+        return (*(jax.tree.map(lambda x: x[None], o) for o in out), local_loss[None])
+
+    specs = (P(axis),) * (3 if aux else 2)
+    fn = jax.shard_map(
+        node_round,
+        mesh=mesh,
+        in_specs=specs,
+        out_specs=specs,
+        check_vma=False,  # model-internal scans carry node-invariant zeros
+                          # (and the pallas int8 path); vma tracking would
+                          # demand pcasts throughout
+    )
+    return jax.jit(fn, donate_argnums=(0, 1) if aux else (0,))
 
 
 def build_fl_round(
@@ -139,7 +209,6 @@ def build_fl_round(
     """One FL round = local_steps SGD steps on node-local data + one
     exchange over ``rel``. Returns a jit'd (stacked_state, stacked_batch) ->
     (stacked_state, metrics) function."""
-    b = registry.bundle(cfg)
     tdm_cfg = fl.TDMFLAConfig(
         comm=fl_cfg.comm,
         compression=fl_cfg.compression,
@@ -147,39 +216,17 @@ def build_fl_round(
         fused=fl_cfg.fused,
     )
 
-    def node_round(state, batch):
-        # state/batch leading dim = 1 (this node's shard); squeeze it
-        state = jax.tree.map(lambda x: x[0], state)
-        batch = jax.tree.map(lambda x: x[0], batch)
-
-        state, local_loss = _local_steps(
-            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
-        )
-
+    def exchange(params):
         # ---- the paper's communication step
-        params = state["params"]
-        with jax.named_scope("exchange"):
-            if fl_cfg.mode == "centralized":
-                params = fl.centralized_round(params, axis)
-            elif fl_cfg.mode == "decentralized":
-                params = fl.decentralized_round(params, axis, n_nodes)
-            else:
-                params, _ = fl.tdm_fla_round(params, rel, axis, n_nodes, tdm_cfg)
-        state = dict(state, params=params)
+        if fl_cfg.mode == "centralized":
+            params = fl.centralized_round(params, axis)
+        elif fl_cfg.mode == "decentralized":
+            params = fl.decentralized_round(params, axis, n_nodes)
+        else:
+            params, _ = fl.tdm_fla_round(params, rel, axis, n_nodes, tdm_cfg)
+        return (params,)
 
-        state = jax.tree.map(lambda x: x[None], state)
-        return state, local_loss[None]
-
-    spec_state = P(axis)
-    fn = jax.shard_map(
-        node_round,
-        mesh=mesh,
-        in_specs=(spec_state, spec_state),
-        out_specs=(spec_state, P(axis)),
-        check_vma=False,  # model-internal scans carry node-invariant zeros;
-                          # vma tracking would demand pcasts throughout
-    )
-    return jax.jit(fn, donate_argnums=(0,))
+    return _round_program(cfg, opt_cfg, mesh, axis, fl_cfg.local_steps, exchange)
 
 
 def build_hierarchical_fl_round(
@@ -209,24 +256,16 @@ def build_hierarchical_fl_round(
     losses) function with the :func:`build_fl_round` contract."""
     from repro.core import fused as fused_lib
 
-    b = registry.bundle(cfg)
     if fl_cfg.compression not in ("none", "int8"):
         raise ValueError(
             f"hierarchical FL supports compression 'none'/'int8', "
             f"got {fl_cfg.compression!r}"
         )
 
-    def node_round(state, batch):
-        state = jax.tree.map(lambda x: x[0], state)
-        batch = jax.tree.map(lambda x: x[0], batch)
-
-        state, local_loss = _local_steps(
-            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
-        )
-
-        with jax.named_scope("exchange"):
-            params = fused_lib.fused_hierarchical_round(
-                state["params"],
+    def exchange(params):
+        return (
+            fused_lib.fused_hierarchical_round(
+                params,
                 intra_rel,
                 inter_rel,
                 data_axis,
@@ -234,33 +273,93 @@ def build_hierarchical_fl_round(
                 n_data,
                 n_pods,
                 compression=fl_cfg.compression,
-            )
-        state = dict(state, params=params)
+            ),
+        )
 
-        state = jax.tree.map(lambda x: x[None], state)
-        return state, local_loss[None]
-
-    spec_state = P((pod_axis, data_axis))
-    fn = jax.shard_map(
-        node_round,
-        mesh=mesh,
-        in_specs=(spec_state, spec_state),
-        out_specs=(spec_state, P((pod_axis, data_axis))),
-        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
+    return _round_program(
+        cfg, opt_cfg, mesh, (pod_axis, data_axis), fl_cfg.local_steps, exchange
     )
-    return jax.jit(fn, donate_argnums=(0,))
 
 
-class RoundFnCache:
+class _ProgramCache:
+    """Compiled round programs by key, built on a miss.
+
+    Misses and hits land on the flight recorder as ``<prefix>.misses`` /
+    ``<prefix>.hits`` counters, each miss also as a ``retrace`` event of
+    ``kind``. With example arguments, a miss records the step's gauges
+    (:func:`_record_step_gauges`, tracing on only) and, in reconcile mode,
+    is ahead-of-time compiled inside a ``span`` via
+    :func:`repro.telemetry.compile_and_check`, so the cached executable is
+    the one the collective oracle verified.
+    """
+
+    def __init__(self, cfg: ModelConfig, prefix: str, kind: str, span: str):
+        self.cfg = cfg
+        self.prefix, self.kind, self.span = prefix, kind, span
+        self._fns: Dict[Any, Callable] = {}
+        self._expected: Dict[Any, Optional[Dict[str, int]]] = {}
+
+    def expected(
+        self, key, oracle: Callable[[], Optional[Dict[str, int]]]
+    ) -> Optional[Dict[str, int]]:
+        """The collective counts ``oracle()`` gives for ``key``'s program,
+        memoized on the key (host work only, no device values)."""
+        if key not in self._expected:
+            self._expected[key] = oracle()
+        return self._expected[key]
+
+    def get(
+        self,
+        key,
+        build: Callable[[], Callable],
+        example_args: Optional[tuple],
+        expected: Callable[[], Optional[Dict[str, int]]],
+        *,
+        context: str,
+        span_args: Dict[str, Any],
+        **event_args,
+    ) -> Callable:
+        """The program of ``key``, made by ``build()`` on a miss.
+        ``example_args`` is ``(state, [aux,] batch)``; ``expected()`` gives
+        the oracle's collective counts and ``context`` the label for
+        ``compile_and_check``; ``span_args`` are the compile span's fields,
+        ``event_args`` the ``retrace`` event's."""
+        rec = telemetry.get_recorder()
+        fn = self._fns.get(key)
+        if fn is not None:
+            rec.counter(f"{self.prefix}.hits")
+            return fn
+        rec.counter(f"{self.prefix}.misses")
+        rec.event(
+            "retrace",
+            cat="compile",
+            kind=self.kind,
+            **event_args,
+            cache_size=len(self._fns),
+        )
+        fn = build()
+        if example_args is not None:
+            _record_step_gauges(self.cfg, example_args[0], example_args[-1], rec)
+            if rec.reconcile:
+                with rec.span(self.span, cat="compile", **span_args):
+                    fn = telemetry.compile_and_check(
+                        fn, example_args, expected(), context=context, recorder=rec
+                    )
+        self._fns[key] = fn
+        return fn
+
+    def __len__(self) -> int:
+        return len(self._fns)
+
+
+class RoundFnCache(_ProgramCache):
     """Compiled FL-round functions keyed by slot relation.
 
     Time-varying schedules revisit topologies (orbits are periodic), so the
     jit cache is keyed on the relation's pair set — each distinct topology
-    compiles once, every revisit is a cache hit. Misses and hits land on
-    the flight recorder (``fl.round_cache.*`` counters plus a ``retrace``
-    event); in reconcile mode each miss is ahead-of-time compiled via
-    :func:`repro.telemetry.compile_and_check` so the cached executable is
-    the one the collective oracle verified.
+    compiles once, every revisit is a cache hit. Misses and hits count as
+    ``fl.round_cache.*``; in reconcile mode each miss is checked against
+    :meth:`expected_collectives` (see :class:`_ProgramCache`).
     """
 
     def __init__(
@@ -272,11 +371,10 @@ class RoundFnCache:
         fl_cfg: FLConfig,
         axis: str = "data",
     ):
+        super().__init__(cfg, "fl.round_cache", "fl_round", "fl.compile")
         self.args = (cfg, opt_cfg, mesh, n_nodes, fl_cfg)
         self.n_nodes = n_nodes
         self.axis = axis
-        self._fns: Dict[Any, Callable] = {}
-        self._expected: Dict[Any, Optional[Dict[str, int]]] = {}
 
     def expected_collectives(
         self, rel: Relation, state: Any
@@ -288,55 +386,28 @@ class RoundFnCache:
         bucket pays the same sidecar structure (int8 ships payload+scales
         per bucket, fused top-k packs values+indices into one payload per
         bucket), so the count is ``matchings × per × n_buckets``."""
-        key = tuple(sorted(rel.pairs))
-        if key in self._expected:
-            return self._expected[key]
         fl_cfg = self.args[4]
-        exp: Optional[Dict[str, int]] = None
-        if fl_cfg.mode == "tdm" and fl_cfg.fused and fl_cfg.comm == "getmeas":
-            # dtype buckets of the fused spec, without touching device
-            # values (no slicing — counters must stay sync-free)
-            n_buckets = len(
-                {leaf.dtype.name for leaf in jax.tree.leaves(state["params"])}
-            )
-            exp = telemetry.expected_tdm_collectives(
-                rel, n_buckets, compression=fl_cfg.compression
-            )
-        self._expected[key] = exp
-        return exp
+
+        def oracle():
+            if fl_cfg.mode == "tdm" and fl_cfg.fused and fl_cfg.comm == "getmeas":
+                return telemetry.expected_tdm_collectives(
+                    rel, _n_buckets(state["params"]), compression=fl_cfg.compression
+                )
+            return None
+
+        return self.expected(tuple(sorted(rel.pairs)), oracle)
 
     def __call__(self, rel: Relation, example_args=None) -> Callable:
-        key = tuple(sorted(rel.pairs))
-        rec = telemetry.get_recorder()
-        fn = self._fns.get(key)
-        if fn is None:
-            rec.counter("fl.round_cache.misses")
-            rec.event(
-                "retrace",
-                cat="compile",
-                kind="fl_round",
-                links=len(rel) // 2,
-                cache_size=len(self._fns),
-            )
-            fn = build_fl_round(*self.args, rel, axis=self.axis)
-            if example_args is not None:
-                _record_step_gauges(self.args[0], *example_args, rec)
-            if rec.reconcile and example_args is not None:
-                with rec.span("fl.compile", cat="compile", links=len(rel) // 2):
-                    fn = telemetry.compile_and_check(
-                        fn,
-                        example_args,
-                        self.expected_collectives(rel, example_args[0]),
-                        context=f"fl_round[{len(rel) // 2} links]",
-                        recorder=rec,
-                    )
-            self._fns[key] = fn
-        else:
-            rec.counter("fl.round_cache.hits")
-        return fn
-
-    def __len__(self) -> int:
-        return len(self._fns)
+        links = len(rel) // 2
+        return self.get(
+            tuple(sorted(rel.pairs)),
+            lambda: build_fl_round(*self.args, rel, axis=self.axis),
+            example_args,
+            lambda: self.expected_collectives(rel, example_args[0]),
+            context=f"fl_round[{links} links]",
+            span_args={"links": links},
+            links=links,
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -594,27 +665,10 @@ def build_groundseg_round(
     :func:`build_fl_round`."""
     from repro.groundseg import aggregation
 
-    b = registry.bundle(cfg)
-    sink_mask = np.zeros((n_nodes,), dtype=bool)
-    sink_mask[sorted(uplink.sinks)] = True
-
-    def node_round(state, batch):
-        state = jax.tree.map(lambda x: x[0], state)
-        batch = jax.tree.map(lambda x: x[0], batch)
-        idx = jax.lax.axis_index(axis)
-        is_sink = jnp.asarray(sink_mask)[idx]
-
-        trained, local_loss = _local_steps(
-            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
-        )
-        # sinks are aggregation infrastructure, not learners
-        state = jax.tree.map(
-            lambda new, old: jnp.where(is_sink, old, new), trained, state
-        )
-
-        with jax.named_scope("exchange"):
-            params = aggregation.groundseg_round(
-                state["params"],
+    def exchange(params):
+        return (
+            aggregation.groundseg_round(
+                params,
                 uplink,
                 downlink,
                 axis,
@@ -622,21 +676,13 @@ def build_groundseg_round(
                 compression=gs_cfg.compression,
                 block=gs_cfg.block,
                 quant_impl=gs_cfg.quant_impl,
-            )
-        state = dict(state, params=params)
+            ),
+        )
 
-        state = jax.tree.map(lambda x: x[None], state)
-        return state, local_loss[None]
-
-    spec_state = P(axis)
-    fn = jax.shard_map(
-        node_round,
-        mesh=mesh,
-        in_specs=(spec_state, spec_state),
-        out_specs=(spec_state, P(axis)),
-        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
+    return _round_program(
+        cfg, opt_cfg, mesh, axis, fl_cfg.local_steps, exchange,
+        sink_mask=np.isin(np.arange(n_nodes), sorted(uplink.sinks)),
     )
-    return jax.jit(fn, donate_argnums=(0,))
 
 
 def build_pipelined_groundseg_round(
@@ -658,53 +704,26 @@ def build_pipelined_groundseg_round(
     threaded across windows."""
     from repro.groundseg import aggregation
 
-    b = registry.bundle(cfg)
-    sink_mask = np.zeros((n_nodes,), dtype=bool)
-    sink_mask[sorted(wp.uplink.sinks)] = True
-
-    def node_round(state, aux, batch):
-        state = jax.tree.map(lambda x: x[0], state)
-        aux = jax.tree.map(lambda x: x[0], aux)
-        batch = jax.tree.map(lambda x: x[0], batch)
-        idx = jax.lax.axis_index(axis)
-        is_sink = jnp.asarray(sink_mask)[idx]
-
-        trained, local_loss = _local_steps(
-            b.loss_fn, opt_cfg, state, batch, fl_cfg.local_steps
+    def exchange(params, aux):
+        params, carry, pending = aggregation.pipelined_window_round(
+            params,
+            aux["carry"],
+            aux["pending"],
+            wp,
+            axis,
+            pool=pool,
+            staleness_decay=gs_cfg.staleness_decay,
+            compression=gs_cfg.compression,
+            block=gs_cfg.block,
+            quant_impl=gs_cfg.quant_impl,
         )
-        state = jax.tree.map(
-            lambda new, old: jnp.where(is_sink, old, new), trained, state
-        )
+        return params, {"carry": carry, "pending": pending}
 
-        with jax.named_scope("exchange"):
-            params, carry, pending = aggregation.pipelined_window_round(
-                state["params"],
-                aux["carry"],
-                aux["pending"],
-                wp,
-                axis,
-                pool=pool,
-                staleness_decay=gs_cfg.staleness_decay,
-                compression=gs_cfg.compression,
-                block=gs_cfg.block,
-                quant_impl=gs_cfg.quant_impl,
-            )
-        state = dict(state, params=params)
-        aux = {"carry": carry, "pending": pending}
-
-        state = jax.tree.map(lambda x: x[None], state)
-        aux = jax.tree.map(lambda x: x[None], aux)
-        return state, aux, local_loss[None]
-
-    spec_state = P(axis)
-    fn = jax.shard_map(
-        node_round,
-        mesh=mesh,
-        in_specs=(spec_state, spec_state, spec_state),
-        out_specs=(spec_state, spec_state, P(axis)),
-        check_vma=False,  # same reason as build_fl_round (+ pallas int8 path)
+    return _round_program(
+        cfg, opt_cfg, mesh, axis, fl_cfg.local_steps, exchange,
+        sink_mask=np.isin(np.arange(n_nodes), sorted(wp.uplink.sinks)),
+        aux=True,
     )
-    return jax.jit(fn, donate_argnums=(0, 1))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -795,12 +814,11 @@ def run_groundseg_fl(
     from repro.groundseg import aggregation
 
     rec = telemetry.get_recorder()
-    n_buckets = len(
-        {leaf.dtype.name for leaf in jax.tree.leaves(state["params"])}
-    )
+    n_buckets = _n_buckets(state["params"])
     prog_cache: Dict[Any, Any] = {}
-    fn_cache: Dict[Any, Any] = {}
-    exp_cache: Dict[Any, Dict[str, int]] = {}
+    fn_cache = _ProgramCache(
+        cfg, "groundseg.round_cache", "groundseg_round", "groundseg.compile"
+    )
     logs: list = []
     for rnd in range(rounds):
         live = set(alive) if alive is not None else set(range(n_nodes))
@@ -829,39 +847,25 @@ def run_groundseg_fl(
             rec.counter("groundseg.route_cache.hits")
         up, down = prog_cache[live_key]
         fn_key = (live_key, pool)
-        if fn_key not in exp_cache:
-            exp_cache[fn_key] = aggregation.expected_collectives(
+        expected = fn_cache.expected(
+            fn_key,
+            lambda: aggregation.expected_collectives(
                 up, down, n_buckets, compression=gs_cfg.compression, pool=pool
-            )
-        expected = exp_cache[fn_key]
+            ),
+        )
         batch = batch_fn(rnd)
-        if fn_key not in fn_cache:
-            rec.counter("groundseg.round_cache.misses")
-            rec.event(
-                "retrace",
-                cat="compile",
-                kind="groundseg_round",
-                round=rnd,
-                pool=pool,
-                cache_size=len(fn_cache),
-            )
-            fn = build_groundseg_round(
+        fn = fn_cache.get(
+            fn_key,
+            lambda: build_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, up, down, pool
-            )
-            _record_step_gauges(cfg, state, batch, rec)
-            if rec.reconcile:
-                with rec.span("groundseg.compile", cat="compile", pool=pool):
-                    fn = telemetry.compile_and_check(
-                        fn,
-                        (state, batch),
-                        expected,
-                        context=f"groundseg_round[pool={pool}]",
-                        recorder=rec,
-                    )
-            fn_cache[fn_key] = fn
-        else:
-            rec.counter("groundseg.round_cache.hits")
-        fn = fn_cache[fn_key]
+            ),
+            (state, batch),
+            lambda: expected,
+            context=f"groundseg_round[pool={pool}]",
+            span_args={"pool": pool},
+            round=rnd,
+            pool=pool,
+        )
         with rec.span(
             "groundseg.round",
             cat="window",
@@ -878,14 +882,9 @@ def run_groundseg_fl(
         for kind, count in expected.items():
             rec.counter(f"groundseg.collectives.{kind}", count)
         live_sats = [v for v in sat_ids if v in live]
-        log_this = log_every > 0 and rnd % log_every == 0
-        if log_this and live_sats:
-            loss_v = float(np.mean(np.asarray(losses)[live_sats]))
-            cons_v = consensus_distance(
-                jax.tree.map(lambda x: np.asarray(x)[live_sats], state["params"])
-            )
-        else:
-            loss_v = cons_v = float("nan")
+        loss_v, cons_v = _live_log_values(
+            losses, state["params"], live_sats, log_every > 0 and rnd % log_every == 0
+        )
         log = GroundSegRoundLog(
             round=rnd,
             loss=loss_v,
@@ -939,8 +938,9 @@ def _run_groundseg_pipelined(
         "carry": aggregation.stacked_zero_buffers(spec, n_nodes),
         "pending": aggregation.stacked_zero_buffers(spec, n_nodes),
     }
-    fn_cache: Dict[Any, Any] = {}
-    exp_cache: Dict[Any, Dict[str, int]] = {}
+    fn_cache = _ProgramCache(
+        cfg, "groundseg.window_cache", "groundseg_window", "groundseg.compile"
+    )
     logs: list = []
     for rnd in range(rounds):
         live = set(alive) if alive is not None else set(range(n_nodes))
@@ -954,39 +954,26 @@ def _run_groundseg_pipelined(
             pool,
             wp.downlink is None,
         )
-        if key not in exp_cache:
-            exp_cache[key] = aggregation.expected_window_collectives(
+        expected = fn_cache.expected(
+            key,
+            lambda: aggregation.expected_window_collectives(
                 wp, n_buckets, compression=gs_cfg.compression, pool=pool
-            )
-        expected = exp_cache[key]
+            ),
+        )
         batch = batch_fn(rnd)
-        if key not in fn_cache:
-            rec.counter("groundseg.window_cache.misses")
-            rec.event(
-                "retrace",
-                cat="compile",
-                kind="groundseg_window",
-                window=wp.window,
-                pool=pool,
-                ages=dict(wp.ages),
-                cache_size=len(fn_cache),
-            )
-            fn = build_pipelined_groundseg_round(
+        fn = fn_cache.get(
+            key,
+            lambda: build_pipelined_groundseg_round(
                 cfg, opt_cfg, mesh, n_nodes, fl_cfg, gs_cfg, wp, pool
-            )
-            _record_step_gauges(cfg, state, batch, rec)
-            if rec.reconcile:
-                with rec.span("groundseg.compile", cat="compile", pool=pool):
-                    fn = telemetry.compile_and_check(
-                        fn,
-                        (state, aux, batch),
-                        expected,
-                        context=f"groundseg_window[{wp.window}, pool={pool}]",
-                        recorder=rec,
-                    )
-            fn_cache[key] = fn
-        else:
-            rec.counter("groundseg.window_cache.hits")
+            ),
+            (state, aux, batch),
+            lambda: expected,
+            context=f"groundseg_window[{wp.window}, pool={pool}]",
+            span_args={"pool": pool},
+            window=wp.window,
+            pool=pool,
+            ages=dict(wp.ages),
+        )
         with rec.span(
             "groundseg.window",
             cat="window",
@@ -998,7 +985,7 @@ def _run_groundseg_pipelined(
             carried=len(wp.residual),
             dropped=len(wp.dropped),
         ):
-            state, aux, losses = fn_cache[key](state, aux, batch)
+            state, aux, losses = fn(state, aux, batch)
         # payload lifecycle: queued -> relayed -> delivered | carried |
         # dropped. Counters are default-on; per-payload instants (with
         # staleness ages) exist only while tracing.
@@ -1022,48 +1009,26 @@ def _run_groundseg_pipelined(
                 rec.event(
                     "payload.queued", cat="payload", window=wp.window, source=src
                 )
-            for src, age in sorted(wp.delivered_ages.items()):
-                rec.event(
-                    "payload.delivered",
-                    cat="payload",
-                    window=wp.window,
-                    source=src,
-                    age=age,
-                )
-            for src, age in sorted(wp.residual.items()):
-                rec.event(
-                    "payload.carried",
-                    cat="payload",
-                    window=wp.window,
-                    source=src,
-                    age=age,
-                )
-            for src, age in sorted(wp.dropped.items()):
-                rec.event(
-                    "payload.dropped",
-                    cat="payload",
-                    window=wp.window,
-                    source=src,
-                    age=age,
-                )
+            for name, ages in (
+                ("payload.delivered", wp.delivered_ages),
+                ("payload.carried", wp.residual),
+                ("payload.dropped", wp.dropped),
+            ):
+                for src, age in sorted(ages.items()):
+                    rec.event(
+                        name, cat="payload", window=wp.window, source=src, age=age
+                    )
         live_sats = [v for v in sat_ids if v in live]
-        log_this = log_every > 0 and rnd % log_every == 0
-        if log_this and live_sats:
-            loss_v = float(np.mean(np.asarray(losses)[live_sats]))
-            cons_v = consensus_distance(
-                jax.tree.map(lambda x: np.asarray(x)[live_sats], state["params"])
-            )
-        else:
-            loss_v = cons_v = float("nan")
+        loss_v, cons_v = _live_log_values(
+            losses, state["params"], live_sats, log_every > 0 and rnd % log_every == 0
+        )
         log = GroundSegRoundLog(
             round=rnd,
             loss=loss_v,
             consensus=cons_v,
             delivered=wp.uplink.delivered_count(),
             covered=(
-                len(wp.downlink.covered - sinks_s)
-                if wp.downlink is not None
-                else 0
+                0 if wp.downlink is None else len(wp.downlink.covered - sinks_s)
             ),
             unreachable=len(wp.uplink.unreachable),
             alive=len(live_sats),
@@ -1076,6 +1041,18 @@ def _run_groundseg_pipelined(
         if on_round is not None:
             on_round(log)
     return state, logs
+
+
+def _live_log_values(losses, stacked_params, live_sats, log_this: bool):
+    """A ground-segment round's logged ``(loss, consensus)``: the mean loss
+    and :func:`consensus_distance` over the live satellites ``live_sats``,
+    read back from the device; NaN for both, reading nothing, on a round
+    not logged or with no live satellite."""
+    if not (log_this and live_sats):
+        return float("nan"), float("nan")
+    return float(np.mean(np.asarray(losses)[live_sats])), consensus_distance(
+        jax.tree.map(lambda x: np.asarray(x)[live_sats], stacked_params)
+    )
 
 
 def consensus_distance(stacked_params) -> float:
@@ -1172,45 +1149,33 @@ class RunResult:
         return self.logs[-1] if self.logs else None
 
 
+# config type -> (mode tag, the function its fields are the arguments of)
+_RUNS = {
+    TDMRun: ("tdm", run_tdm_rounds),
+    ConstellationRun: ("constellation", run_constellation_fl),
+    GroundSegRun: ("groundseg", run_groundseg_fl),
+}
+
+
 def run(run_cfg) -> RunResult:
     """One driver entry point over the three FL modes.
 
     Dispatches on the config dataclass type — :class:`TDMRun` →
     :func:`run_tdm_rounds`, :class:`ConstellationRun` →
     :func:`run_constellation_fl`, :class:`GroundSegRun` →
-    :func:`run_groundseg_fl` — and normalizes the ``(state, logs)`` returns
-    into one :class:`RunResult`. The underlying functions are unchanged
-    (and remain directly callable); this is pure plumbing so examples and
-    higher drivers can switch modes by swapping a config object.
+    :func:`run_groundseg_fl` — calling the function with the config's
+    fields as keyword arguments, and normalizes the ``(state, logs)``
+    returns into one :class:`RunResult`. The underlying functions are
+    unchanged (and remain directly callable); this is pure plumbing so
+    examples and higher drivers can switch modes by swapping a config
+    object. The fields are passed as they are, not copied: a caller may
+    still mutate ``alive`` between rounds.
     """
-    if isinstance(run_cfg, TDMRun):
-        state, logs = run_tdm_rounds(
-            run_cfg.cache, run_cfg.state, run_cfg.relations, run_cfg.batch_fn,
-            alive=run_cfg.alive, on_round=run_cfg.on_round,
-            log_every=run_cfg.log_every,
-        )
-        return RunResult("tdm", state, logs)
-    if isinstance(run_cfg, ConstellationRun):
-        state, logs = run_constellation_fl(
-            run_cfg.cfg, run_cfg.opt_cfg, run_cfg.mesh, run_cfg.n_nodes,
-            run_cfg.fl_cfg, run_cfg.plan, run_cfg.state, run_cfg.batch_fn,
-            rounds=run_cfg.rounds, alive=run_cfg.alive,
-            on_round=run_cfg.on_round, optimize=run_cfg.optimize,
-            antennas=run_cfg.antennas, payload_bytes=run_cfg.payload_bytes,
-            acquisition_s=run_cfg.acquisition_s, log_every=run_cfg.log_every,
-        )
-        return RunResult("constellation", state, logs)
-    if isinstance(run_cfg, GroundSegRun):
-        state, logs = run_groundseg_fl(
-            run_cfg.cfg, run_cfg.opt_cfg, run_cfg.mesh, run_cfg.n_nodes,
-            run_cfg.fl_cfg, run_cfg.gs_cfg, run_cfg.plan, run_cfg.state,
-            run_cfg.batch_fn, run_cfg.sinks, run_cfg.rounds,
-            alive=run_cfg.alive, on_round=run_cfg.on_round,
-            optimize=run_cfg.optimize, antennas=run_cfg.antennas,
-            payload_bytes=run_cfg.payload_bytes,
-            acquisition_s=run_cfg.acquisition_s, log_every=run_cfg.log_every,
-        )
-        return RunResult("groundseg", state, logs)
+    for cls, (mode, fn) in _RUNS.items():
+        if isinstance(run_cfg, cls):
+            fields = dataclasses.fields(run_cfg)
+            state, logs = fn(**{f.name: getattr(run_cfg, f.name) for f in fields})
+            return RunResult(mode, state, logs)
     raise TypeError(
         f"run() takes a TDMRun / ConstellationRun / GroundSegRun config, "
         f"got {type(run_cfg).__name__}"

@@ -16,7 +16,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models import transformer
+from repro.models import moe, transformer
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.models.layers import dtype_of
 
@@ -36,6 +36,11 @@ class ModelBundle:
     # ------------------------------------------------------------ step fns
     def loss_fn(self, params, batch):
         return transformer.loss_fn(params, batch, self.cfg)
+
+    def step_gauges(self, tokens: int) -> Dict[str, int]:
+        """Gauges of a training step of ``tokens`` tokens that the model's
+        layers define (a dropless MoE's: :func:`repro.models.moe.step_gauges`)."""
+        return moe.step_gauges(tokens, self.cfg)
 
     def prefill_fn(self, params, batch, max_len: int):
         return transformer.prefill(

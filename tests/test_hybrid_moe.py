@@ -374,11 +374,14 @@ def test_param_counts():
                zip(jax.tree.leaves(program), jax.tree.leaves(shapes)))
 
 
-# sha256 of each solo round's lowered module, locations stripped, before the
-# hybrid's layers came in: the change leaves these programs as they were
+# sha256 of each solo round's lowered module (CPU lowering, locations
+# stripped): a guard of the benchmark cells' round programs, so that a change
+# to the FL driver or to a layer another cell uses shows as a changed hash
 SOLO_MODULES = {
     ("mamba2-780m", "solo"):
         "55a5d60c6fdec16e99b89340e8db41c1cba45604803ba6e593015d6c47f093d9",
+    ("nemotron-3-nano-30b-a3b", "solo.b2x8192"):
+        "0fba25ee609e85dd5748ac70e0ee30eaa51e4ff41e3fbefd4f5842483845f2d9",
     ("whisper-base", "solo.b64x448"):
         "71a4eb60271cb04550a2b6d4f6e447c090142d473b9d77475a32658211d4c5ac",
 }
