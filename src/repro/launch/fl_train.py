@@ -109,17 +109,16 @@ def _local_steps(loss_fn, opt_cfg: adamw.OptConfig, state, batch, n_steps: int):
 def _record_step_gauges(cfg: ModelConfig, state, batch, rec) -> None:
     """Gauges of one local step, per node, from one node's abstract shapes:
     the model's own (:meth:`repro.models.registry.ModelBundle.step_gauges`:
-    a dropless MoE's ``moe.experts_held``, ``moe.expert_rows`` and
-    ``moe.expert_rows_first``), then ``fl.remat_saved_bytes``, the bytes
-    the forward keeps for its backward by remat policy (a Mamba-2 layer's
-    saved in-projections and SSD output; 0 for a model without Mamba
-    layers). Costs one trace of the loss, so it is recorded on a
-    round-cache miss with tracing on only."""
+    ``attention.kernel_layers``, and a dropless MoE's ``moe.experts_held``,
+    ``moe.expert_rows`` and ``moe.expert_rows_first``), then
+    ``fl.remat_saved_bytes``, the bytes the forward keeps for its backward
+    by remat policy (a Mamba-2 layer's saved in-projections and SSD output;
+    0 for a model without Mamba layers). Costs one trace of the loss, so
+    it is recorded on a round-cache miss with tracing on only."""
     if not rec.tracing:
         return
     bundle = registry.bundle(cfg)
-    tokens = int(np.prod(batch["tokens"].shape[2:]))
-    for name, value in bundle.step_gauges(tokens).items():
+    for name, value in bundle.step_gauges(*batch["tokens"].shape[2:]).items():
         telemetry.set_gauge(name, value, rec=rec)
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), state["params"]

@@ -13,8 +13,11 @@ Design (DESIGN.md §7): O(S) memory online-softmax attention with
 - a manual flash backward (``custom_vjp``): forward saves only (out, lse);
   backward recomputes probabilities blockwise from the saved lse.
 
-The Pallas TPU kernel (:mod:`repro.kernels.flash_attention`) implements the
-same spec; ``naive_attention`` here is the semantic oracle for both.
+On a TPU, ``flash_attention_train`` runs the bundled Pallas splash-attention
+kernels instead, forward and backward, wherever they implement the spec and
+the shapes tile (:func:`kernel_applies`): each score tile then stays in
+VMEM, where the blocked path above makes it an XLA array in HBM.
+``naive_attention`` here is the semantic oracle for every path.
 """
 
 from __future__ import annotations
@@ -315,38 +318,113 @@ def _flash_backward(q, k, v, out5, lse, g, spec: AttnSpec):
 # public API
 # ---------------------------------------------------------------------------
 
-def _divisible(q, k, spec: AttnSpec) -> bool:
+def _divisible(q_shape, k_shape, spec: AttnSpec) -> bool:
     return (
-        q.shape[1] % spec.block_q == 0
-        and k.shape[1] % spec.block_k == 0
-        and q.shape[1] >= spec.block_q
-        and k.shape[1] >= spec.block_k
+        q_shape[1] % spec.block_q == 0
+        and k_shape[1] % spec.block_k == 0
+        and q_shape[1] >= spec.block_q
+        and k_shape[1] >= spec.block_k
     )
+
+
+def kernel_applies(q_shape, k_shape, spec: AttnSpec) -> bool:
+    """Whether ``flash_attention_train`` runs the fused Pallas kernel for
+    q of ``q_shape`` (B, Sq, H, hd) and k of ``k_shape`` (B, Skv, KV, hd):
+    on a TPU, for a causal or full spec (no window, no softcap), with
+    ``head_dim`` a multiple of 128, ``H`` a multiple of ``KV``, and both
+    lengths whole numbers of the spec's blocks, which are multiples of 128.
+    Elsewhere the blocked pure-JAX path runs."""
+    _, _, H, hd = q_shape
+    KV = k_shape[2]
+    return (
+        jax.default_backend() == "tpu"
+        and spec.window is None
+        and spec.softcap is None
+        and hd % 128 == 0
+        and H % KV == 0
+        and spec.block_q % 128 == 0
+        and spec.block_k % 128 == 0
+        and _divisible(q_shape, k_shape, spec)
+    )
+
+
+@functools.lru_cache(maxsize=32)
+def _splash_kernel(Sq: int, Skv: int, H: int, causal: bool, bq: int, bk: int,
+                   interpret: bool):
+    """The splash-attention kernel for one example: q (H, Sq, hd) against
+    k, v (KV, Skv, hd), KV heads read by index map (head h reads KV head
+    h // (H // KV)). Causally empty kv blocks are skipped, in the forward
+    and in both backward passes (``dq``, ``dkv``). Made once per shape, as
+    concrete arrays, whatever trace asks first."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as splash
+
+    mask_cls = splash.CausalMask if causal else splash.FullMask
+    mask = splash.MultiHeadMask([mask_cls((Sq, Skv))] * H)
+    sizes = splash.BlockSizes(
+        block_q=bq, block_kv=bk,
+        block_q_dkv=bq, block_kv_dkv=bk,
+        block_q_dq=bq, block_kv_dq=bk,
+    )
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha_single_device(
+            mask, block_sizes=sizes, interpret=interpret
+        )
+
+
+def splash_attention(q, k, v, spec: AttnSpec, interpret: bool = False):
+    """Attention through the Pallas splash kernels: q (B, Sq, H, hd), k, v
+    (B, Skv, KV, hd). The MXU operands stay in the inputs' dtype with f32
+    accumulation and f32 softmax statistics; the kernel applies no scale, so
+    q is scaled in f32 and rounded once. Differentiable: the kernels'
+    forward saves (out, lse) and the backward runs their ``dq`` and ``dkv``
+    passes."""
+    _, Sq, H, hd = q.shape
+    kernel = _splash_kernel(Sq, k.shape[1], H, spec.causal, spec.block_q,
+                            spec.block_k, interpret)
+    qs = (q.astype(jnp.float32) * spec.scale(hd)).astype(q.dtype)
+    heads_major = lambda x: x.transpose(0, 2, 1, 3)
+    out = jax.vmap(kernel)(heads_major(qs), heads_major(k), heads_major(v))
+    return heads_major(out)
+
+
+def _attention_by_ad(q, k, spec: AttnSpec):
+    """The attention that ``flash_attention_train`` differentiates by AD, or
+    None for the blocked pure-JAX path and its manual backward: the splash
+    kernels where :func:`kernel_applies` (their own forward saves (out, lse)
+    and their backward runs the ``dq``/``dkv`` passes), the naive oracle for
+    shapes that don't tile (tiny smoke configs)."""
+    if kernel_applies(q.shape, k.shape, spec):
+        return splash_attention
+    if not _divisible(q.shape, k.shape, spec):
+        return naive_attention
+    return None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def flash_attention_train(q, k, v, spec: AttnSpec):
-    """Training attention (q_offset=0). Falls back to the naive oracle for
-    shapes that don't tile (tiny smoke configs)."""
-    if not _divisible(q, k, spec):
-        return naive_attention(q, k, v, spec)
+    """Training attention (q_offset=0): the Pallas splash kernels where
+    :func:`kernel_applies`, else the blocked pure-JAX path, which falls back
+    to the naive oracle for shapes that don't tile (tiny smoke configs)."""
+    attend = _attention_by_ad(q, k, spec)
+    if attend is not None:
+        return attend(q, k, v, spec)
     out, _ = _flash_forward(q, k, v, spec)
     return out
 
 
 def _fa_fwd(q, k, v, spec: AttnSpec):
-    if not _divisible(q, k, spec):
-        # fall back to AD through the naive path
-        out, vjp = jax.vjp(lambda q, k, v: naive_attention(q, k, v, spec), q, k, v)
+    attend = _attention_by_ad(q, k, spec)
+    if attend is not None:
+        out, vjp = jax.vjp(lambda q, k, v: attend(q, k, v, spec), q, k, v)
         return out, (None, vjp)
     out, (out5, lse) = _flash_forward(q, k, v, spec)
     return out, ((q, k, v, out5, lse), None)
 
 
 def _fa_bwd(spec: AttnSpec, res, g):
-    saved, naive_vjp = res
+    saved, vjp = res
     if saved is None:
-        return naive_vjp(g)
+        return vjp(g)
     q, k, v, out5, lse = saved
     return _flash_backward(q, k, v, out5, lse, g, spec)
 

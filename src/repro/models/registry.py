@@ -37,10 +37,16 @@ class ModelBundle:
     def loss_fn(self, params, batch):
         return transformer.loss_fn(params, batch, self.cfg)
 
-    def step_gauges(self, tokens: int) -> Dict[str, int]:
-        """Gauges of a training step of ``tokens`` tokens that the model's
-        layers define (a dropless MoE's: :func:`repro.models.moe.step_gauges`)."""
-        return moe.step_gauges(tokens, self.cfg)
+    def step_gauges(self, batch: int, seq: int) -> Dict[str, int]:
+        """Gauges of a training step over ``batch`` x ``seq`` tokens that the
+        model's layers define: ``attention.kernel_layers``, the attention
+        calls that run the fused Pallas kernel
+        (:func:`repro.models.transformer.attention_kernel_layers`), and a
+        dropless MoE's (:func:`repro.models.moe.step_gauges`)."""
+        return {
+            "attention.kernel_layers": transformer.attention_kernel_layers(self.cfg, batch, seq),
+            **moe.step_gauges(batch * seq, self.cfg),
+        }
 
     def prefill_fn(self, params, batch, max_len: int):
         return transformer.prefill(

@@ -44,6 +44,7 @@ from repro.models.attention import (
     AttnSpec,
     flash_attention_decode,
     flash_attention_train,
+    kernel_applies,
 )
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -172,6 +173,27 @@ def _attn_spec(cfg: ModelConfig, desc: LayerDesc, causal: bool = True) -> AttnSp
         block_q=cfg.attn_block_q,
         block_k=cfg.attn_block_k,
     )
+
+
+def attention_kernel_layers(cfg: ModelConfig, batch: int, seq: int) -> int:
+    """How many attention calls of one training step over ``batch`` x ``seq``
+    tokens run the fused Pallas kernel, by the predicate that dispatches
+    them (:func:`repro.models.attention.kernel_applies`): self-attention and
+    cross-attention of each unit, and the encoder's layers."""
+    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = (batch, seq, H, hd)
+    full = _attn_spec(cfg, LayerDesc("attn"), causal=False)
+    n = 0
+    for d in scan_unit(cfg):
+        if d.mixer == "attn":
+            n += kernel_applies(q, (batch, seq, KV, hd), _attn_spec(cfg, d))
+            if d.cross:
+                n += kernel_applies(q, (batch, cfg.enc_frames, KV, hd), full)
+    n *= n_units(cfg)
+    if cfg.enc_dec:
+        frames = (batch, cfg.enc_frames)
+        n += cfg.n_enc_layers * kernel_applies((*frames, H, hd), (*frames, KV, hd), full)
+    return n
 
 
 def attn_train(

@@ -441,3 +441,30 @@ def test_expert_kernel_reader_sums_the_grouped_matmuls():
     assert reader.read(ctx) == pytest.approx(1.25)
     ctx["trace"] = trace.Trace(chips={"/device:TPU:0": ops[2:]}, host=[])
     assert reader.read(ctx) is None
+
+
+def test_attention_kernel_reader_sums_the_splash_kernels():
+    """``attention.kernel_ms`` sums the device time of the splash-attention
+    kernels (forward, ``dq``, ``dkv``) per round and chip, and no other
+    custom-call; a program whose attention runs the blocked pure-JAX path
+    reads nothing."""
+    from bench import trace
+
+    reader = harness.load_module(
+        ROOT / "bench" / "metrics" / "attention.kernel_ms.py", "attention_kernel_ms"
+    )
+    call = 'custom-call(%a, %b), custom_call_target="tpu_custom_call"'
+    ops = [
+        trace.Op(0, 4e6, f"%splash_mha_fwd_residuals.2 = (bf16[2,32,8192,128]{{3,2,1,0}}, "
+                         f"f32[2,32,8192,128]{{3,2,1,0}}) {call}"),
+        trace.Op(4e6, 5e6, f"%splash_mha_dq_no_residuals.1 = bf16[2,32,8192,128]{{3,2,1,0}} {call}"),
+        trace.Op(5e6, 7e6, f"%splash_mha_dkv_no_residuals.1 = (bf16[2,2,8192,128]{{3,2,1,0}}, "
+                           f"bf16[2,2,8192,128]{{3,2,1,0}}) {call}"),
+        trace.Op(7e6, 9e6, f"%gmm.84 = bf16[98304,1920]{{1,0}} {call}"),
+        trace.Op(9e6, 9.5e6, "%fusion.1 = f32[8]{0} fusion(%d), kind=kLoop"),
+    ]
+    ctx = {"trace": trace.Trace(chips={"/device:TPU:0": ops}, host=[]),
+           "lo": 0, "hi": 1e7, "rounds": 2}
+    assert reader.read(ctx) == pytest.approx(3.5)
+    ctx["trace"] = trace.Trace(chips={"/device:TPU:0": ops[3:]}, host=[])
+    assert reader.read(ctx) is None

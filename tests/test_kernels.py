@@ -110,6 +110,80 @@ def test_model_flash_gradients_match_naive():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
 
 
+# the TPU path of flash_attention_train: the Pallas splash kernels
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_kernel_path_matches_naive(causal, monkeypatch):
+    """Through ``flash_attention_train``'s custom_vjp with the backend seen as
+    a TPU (kernels in interpret mode), a tiling GQA shape takes the splash
+    kernels, and their output and gradients with respect to q, k and v match
+    AD through the naive oracle in f32 at HIGHEST precision."""
+    import functools
+
+    from repro.models import attention as attn
+
+    B, S, H, KV, hd = 2, 1024, 8, 2, 128
+    spec = AttnSpec(causal=causal)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert attn.kernel_applies((B, S, H, hd), (B, S, KV, hd), spec)
+    calls = []
+    splash = attn.splash_attention
+
+    def interpreted(*args, **kw):
+        calls.append(args[0].shape)
+        return splash(*args, interpret=True, **kw)
+
+    monkeypatch.setattr(attn, "splash_attention", interpreted)
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = rand(ks[0], (B, S, H, hd), jnp.float32)
+    k = rand(ks[1], (B, S, KV, hd), jnp.float32)
+    v = rand(ks[2], (B, S, KV, hd), jnp.float32)
+    w = rand(ks[3], (B, S, H, hd), jnp.float32)
+
+    def loss(attend, q, k, v):
+        return jnp.sum(w * attend(q, k, v, spec))
+
+    got = jax.jit(functools.partial(flash_attention_train, spec=spec))(q, k, v)
+    grads = jax.jit(jax.grad(functools.partial(loss, flash_attention_train), (0, 1, 2)))(q, k, v)
+    assert len(calls) == 2  # the forward, and the custom_vjp's forward
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    with jax.default_matmul_precision("highest"):
+        want = naive_attention(q, k, v, spec)
+        want_grads = jax.grad(functools.partial(loss, naive_attention), (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+SOLO8K = ((2, 8192, 32, 128), (2, 8192, 2, 128))  # q and k shapes
+KERNEL_DISPATCH = {
+    # name: (q shape, k shape, spec, kernel on a TPU)
+    "causal_gqa": (*SOLO8K, AttnSpec(causal=True), True),
+    "full": (*SOLO8K, AttnSpec(causal=False), True),
+    "cross": ((2, 1024, 8, 128), (2, 2048, 8, 128), AttnSpec(causal=False), True),
+    "head_dim_64": ((2, 1024, 8, 64), (2, 1024, 2, 64), AttnSpec(), False),
+    "window": (*SOLO8K, AttnSpec(window=4096), False),
+    "softcap": (*SOLO8K, AttnSpec(softcap=50.0), False),
+    "length_not_tiling": ((2, 1536, 8, 128), (2, 1536, 2, 128), AttnSpec(), False),
+    "shorter_than_a_block": ((2, 512, 8, 128), (2, 512, 2, 128), AttnSpec(), False),
+    "heads_not_grouped": ((2, 1024, 6, 128), (2, 1024, 4, 128), AttnSpec(), False),
+    "blocks_off_the_lanes": ((2, 1024, 8, 128), (2, 1024, 2, 128),
+                             AttnSpec(block_q=64, block_k=64), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_DISPATCH))
+def test_kernel_dispatch_follows_backend_spec_and_shapes(case, monkeypatch):
+    """``kernel_applies`` picks the splash kernels only on a TPU, for a causal
+    or full spec at shapes that tile; everything else keeps the blocked
+    pure-JAX path."""
+    from repro.models.attention import kernel_applies
+
+    q_shape, k_shape, spec, on_tpu = KERNEL_DISPATCH[case]
+    assert not kernel_applies(q_shape, k_shape, spec)  # the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_applies(q_shape, k_shape, spec) == on_tpu
+
+
 # ---------------------------------------------------------------------------
 # SSD kernel vs sequential oracle
 # ---------------------------------------------------------------------------

@@ -919,6 +919,31 @@ def test_hybrid_round_carries_layer_scopes_and_moe_gauges():
     # the bound, so the buffer has that one size
     assert gauges["moe.expert_rows_first"] == gauges["moe.expert_rows"]
     assert gauges["fl.remat_saved_bytes"] > 0
+    # on the CPU no attention call takes the fused kernel
+    assert gauges["attention.kernel_layers"] == 0
+
+
+@pytest.mark.parametrize("config,seq,backend,layers", [
+    ("nemotron-3-nano-30b-a3b", 8192, "tpu", 1),
+    ("nemotron-3-nano-30b-a3b", 8192, "cpu", 0),
+    ("mamba2-780m", 512, "tpu", 0),
+])
+def test_attention_kernel_layers_gauge(config, seq, backend, layers, monkeypatch):
+    """``attention.kernel_layers`` counts the attention calls of a training
+    step that take the fused Pallas kernel, by the dispatch's own
+    predicate: solo8k's one NoPE GQA layer at 2 x 8192 on a TPU, none on
+    the CPU, and none in the attention-free ``mamba2-780m``."""
+    import jax
+
+    from bench import harness
+    from repro.configs import archs
+    from repro.models import registry
+
+    conf, model = harness.load_config(config)
+    cfg = model.program_config(conf, archs)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    gauges = registry.bundle(cfg).step_gauges(2, seq)
+    assert gauges["attention.kernel_layers"] == layers
 
 
 def test_moe_step_gauges_of_the_benchmark_cells():

@@ -1,4 +1,6 @@
-"""Compile the ``tdm_compress`` kernels for a described TPU v5e chip.
+"""Compile the ``tdm_compress`` kernels, and the Pallas kernels of the
+benchmark's layers (the MoE's grouped matmuls, the training attention's
+splash kernels), for a described TPU v5e chip.
 
 Interpret mode (``tests/test_kernels.py``) checks what the kernels compute;
 it cannot see what the TPU's Mosaic compiler refuses — tile shapes that
@@ -6,7 +8,10 @@ break the (8, 128) / (32, 128) layout rules, or tiles that overflow VMEM.
 These tests lower every kernel at the length of one node's fused
 ``mamba2-780m`` buffer for a ``v5e:2x2`` topology that is described, not
 attached, and check that the kernel survived as a ``tpu_custom_call``
-named after its entry point (``tdm_quantize``, ...), as a trace shows it.
+named after its entry point (``tdm_quantize``, ...), as a trace shows it;
+the layers' kernels are lowered inside one layer's forward and backward
+at ``nemotron-3-nano-30b-a3b``'s published widths and the benchmark's
+2 x 8192 tokens.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
@@ -166,3 +171,50 @@ def test_moe_grouped_matmuls_compile_for_v5e(one_chip, no_compile_cache, monkeyp
     switches = [l.split("conditional(")[0] for l in text.splitlines() if " conditional(" in l]
     assert len(switches) == 2
     assert not any(f"[{ladder[-1]}," in out for out in switches), switches
+
+
+def test_attention_kernels_compile_for_v5e(one_chip, no_compile_cache, monkeypatch):
+    """One ``nemotron-3-nano-30b-a3b`` attention layer at the benchmark's
+    shape (B 2, S 8192, 32 query heads sharing 2 KV heads of 128, bf16,
+    causal), forward and backward under the round's remat policy, takes the
+    TPU path of ``flash_attention_train``: the splash kernels, named after
+    their entry points as a trace shows them (``attention.kernel_ms`` reads
+    them), a forward twice (the output, and its recompute for the backward)
+    and one ``dkv`` and one ``dq`` pass. No f32 score tile of the blocked
+    pure-JAX path (f32[..., 512, 1024], one q block by one kv block) is left
+    in the module."""
+    import re
+
+    from repro.configs import archs
+    from repro.models import transformer
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the described chip
+    cfg = archs.get("nemotron-3-nano-30b-a3b")
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (32, 2, 128)
+    desc = transformer.LayerDesc("attn")
+    params = jax.eval_shape(
+        lambda k: transformer.init_attention(k, cfg)[0], jax.random.PRNGKey(0)
+    )
+    params = jax.tree.map(lambda x: _arg(x.shape, x.dtype, one_chip), params)
+    x = _arg((2, 8192, cfg.d_model), jnp.bfloat16, one_chip)
+
+    def layer(p, x):
+        return x + transformer.attn_train(p, x, None, cfg, desc)
+
+    def loss(p, x):
+        # the gradient needs the layer's output, as the next layer's does
+        y = transformer.remat_layer(layer, cfg)(p, x)
+        return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    kernels = sorted(
+        line.split("=")[0].split()[-1].lstrip("%").split(".")[0]
+        for line in text.splitlines() if "tpu_custom_call" in line
+    )
+    assert kernels == [
+        "splash_mha_dkv_no_residuals",
+        "splash_mha_dq_no_residuals",
+        "splash_mha_fwd_residuals",
+        "splash_mha_fwd_residuals",
+    ]
+    assert not re.search(r"f32\[[\d,]*512,1024\]", text)
